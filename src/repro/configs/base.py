@@ -86,8 +86,9 @@ class ModelConfig:
     #            collective volume scales with weights, not activations
     parallelism: str = "tp"
 
-    # attention implementation: naive | chunked (jnp online-softmax) —
-    # Pallas kernels are selected separately by the launcher when on TPU
+    # attention implementation: naive | chunked (jnp online-softmax) |
+    # pallas (kernels/: flash prefill, flash-decode).  launch/serve.py's
+    # Server selects "pallas" on a TPU backend; nothing else does.
     attn_impl: str = "chunked"
     attn_chunk: int = 1024
 

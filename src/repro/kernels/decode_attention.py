@@ -1,10 +1,10 @@
 """decode_attention — flash-decode: one query token against a long KV cache.
 
-Grid: (batch*q_heads, S/bk).  The KV cache streams block-by-block through
-VMEM while running max/sum/accumulator scratch carries the online softmax;
-``kv_len`` arrives as a scalar-prefetch operand and blocks entirely past it
-are skipped (``pl.when``) — the static schedule only *fetches* what the
-access plan says will be read, CAPre-style.
+Grid: (batch*kv_heads, S/bk).  Each program holds the G query heads that
+share one KV head, so every KV block is fetched once per group.  The KV
+cache streams block-by-block through VMEM while running max/sum/accumulator
+scratch carries the online softmax; ``kv_len`` arrives as a scalar-prefetch
+operand and blocks entirely past it are skipped (``pl.when``).
 """
 
 from __future__ import annotations
@@ -32,20 +32,20 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *
 
     @pl.when(j * bk < kv_len)  # skip blocks entirely past the valid length
     def _compute():
-        q = q_ref[...].astype(jnp.float32)  # [1, D]
-        k = k_ref[0].astype(jnp.float32)  # [bk, D] (may arrive quantized)
-        v = v_ref[0].astype(jnp.float32)
+        q = q_ref[...].astype(jnp.float32)  # [G, D]
+        k = k_ref[...].astype(jnp.float32)  # [bk, D] (may arrive quantized)
+        v = v_ref[...].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * (1.0 / (q.shape[-1] ** 0.5))  # [1, bk]
-        kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+        ) * (1.0 / (q.shape[-1] ** 0.5))  # [G, bk]
+        kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(kpos < kv_len, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_ref[...]  # [G, 1]
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
+        l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
@@ -53,15 +53,15 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *
 
     @pl.when(j == n_kv - 1)
     def _flush():
-        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 def decode_attention_kernel(q, k, v, kv_len, *, block_k: int = 512,
                             interpret: bool = True):
-    """q [BH, D]; k, v [BKV, S, D]; kv_len scalar int32 -> [BH, D]."""
-    BH, D = q.shape
-    BKV, S, _ = k.shape
-    G = BH // BKV
+    """q [BKV, G, D] (the G query heads of each KV head); k, v [BKV, S, D];
+    kv_len scalar int32 -> [BKV, G, D]."""
+    BKV, G, D = q.shape
+    S = k.shape[1]
     bk = min(block_k, S)
     assert S % bk == 0
     n_kv = S // bk
@@ -70,20 +70,20 @@ def decode_attention_kernel(q, k, v, kv_len, *, block_k: int = 512,
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(BH, n_kv),
+            grid=(BKV, n_kv),
             in_specs=[
-                pl.BlockSpec((1, D), lambda h, j, len_ref: (h, 0)),
-                pl.BlockSpec((1, bk, D), lambda h, j, len_ref, G=G: (h // G, j, 0)),
-                pl.BlockSpec((1, bk, D), lambda h, j, len_ref, G=G: (h // G, j, 0)),
+                pl.BlockSpec((None, G, D), lambda g, j, len_ref: (g, 0, 0)),
+                pl.BlockSpec((None, bk, D), lambda g, j, len_ref: (g, j, 0)),
+                pl.BlockSpec((None, bk, D), lambda g, j, len_ref: (g, j, 0)),
             ],
-            out_specs=pl.BlockSpec((1, D), lambda h, j, len_ref: (h, 0)),
+            out_specs=pl.BlockSpec((None, G, D), lambda g, j, len_ref: (g, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((1,), jnp.float32),
-                pltpu.VMEM((1,), jnp.float32),
-                pltpu.VMEM((1, D), jnp.float32),
+                pltpu.VMEM((G, 1), jnp.float32),
+                pltpu.VMEM((G, 1), jnp.float32),
+                pltpu.VMEM((G, D), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((BH, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((BKV, G, D), q.dtype),
         interpret=interpret,
         name="decode_attention",
-    )(jnp.asarray([kv_len], jnp.int32), q, k, v)
+    )(jnp.asarray(kv_len, jnp.int32).reshape(1), q, k, v)

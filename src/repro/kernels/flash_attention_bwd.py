@@ -34,10 +34,10 @@ def _recompute_p_ds(q, k, v, do, lse, delta, scale, causal, q_offset, qi, kj, bq
         qpos = q_offset + qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         kpos = kj * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
         s = jnp.where(kpos <= qpos, s, NEG_INF)
-    p = jnp.exp(s - lse[:, None])
+    p = jnp.exp(s - lse)  # lse, delta: [bq, 1]
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[:, None]) * scale
+    ds = p * (dp - delta) * scale
     return p, ds
 
 
@@ -53,22 +53,22 @@ def _dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
     p, ds = _recompute_p_ds(
-        q_ref[0], k_ref[0], v_ref[0], do_ref[0], lse_ref[0], delta_ref[0],
+        q_ref[...], k_ref[...], v_ref[...], do_ref[...], lse_ref[...], delta_ref[...],
         scale, causal, q_offset, i, j, bq, bk,
     )
     dv_acc[...] += jax.lax.dot_general(
-        p.astype(do_ref.dtype), do_ref[0], (((0,), (0,)), ((), ())),
+        p.astype(do_ref.dtype), do_ref[...], (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
     dk_acc[...] += jax.lax.dot_general(
-        ds.astype(q_ref.dtype), q_ref[0], (((0,), (0,)), ((), ())),
+        ds.astype(q_ref.dtype), q_ref[...], (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
 
     @pl.when(i == n_q - 1)
     def _flush():
-        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+        dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc, *,
@@ -81,23 +81,23 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc, 
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
     _, ds = _recompute_p_ds(
-        q_ref[0], k_ref[0], v_ref[0], do_ref[0], lse_ref[0], delta_ref[0],
+        q_ref[...], k_ref[...], v_ref[...], do_ref[...], lse_ref[...], delta_ref[...],
         scale, causal, q_offset, i, j, bq, bk,
     )
     dq_acc[...] += jax.lax.dot_general(
-        ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
+        ds.astype(k_ref.dtype), k_ref[...], (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
 
     @pl.when(j == n_kv - 1)
     def _flush():
-        dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
+        dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
 
 
 def flash_attention_bwd_kernel(q, k, v, do, lse, delta, *, causal: bool,
                                q_offset: int = 0, block_q: int = 128,
                                block_k: int = 128, interpret: bool = True):
-    """q/do [BH, Sq, D]; k/v [BKV, Sk, D]; lse/delta [BH, Sq].
+    """q/do [BH, Sq, D]; k/v [BKV, Sk, D]; lse/delta [BH, Sq, 1].
 
     Returns (dq [BH, Sq, D], dk_per_qhead [BH, Sk, D], dv_per_qhead
     [BH, Sk, D]) — the wrapper reduces dk/dv over each kv head's group."""
@@ -114,16 +114,16 @@ def flash_attention_bwd_kernel(q, k, v, do, lse, delta, *, causal: bool,
                           q_offset=q_offset, n_q=n_q),
         grid=(BH, n_kv, n_q),
         in_specs=[
-            pl.BlockSpec((1, bq, D), lambda h, j, i: (h, i, 0)),  # q
-            pl.BlockSpec((1, bk, D), lambda h, j, i, G=G: (h // G, j, 0)),  # k
-            pl.BlockSpec((1, bk, D), lambda h, j, i, G=G: (h // G, j, 0)),  # v
-            pl.BlockSpec((1, bq, D), lambda h, j, i: (h, i, 0)),  # do
-            pl.BlockSpec((1, bq), lambda h, j, i: (h, i)),  # lse
-            pl.BlockSpec((1, bq), lambda h, j, i: (h, i)),  # delta
+            pl.BlockSpec((None, bq, D), lambda h, j, i: (h, i, 0)),  # q
+            pl.BlockSpec((None, bk, D), lambda h, j, i, G=G: (h // G, j, 0)),  # k
+            pl.BlockSpec((None, bk, D), lambda h, j, i, G=G: (h // G, j, 0)),  # v
+            pl.BlockSpec((None, bq, D), lambda h, j, i: (h, i, 0)),  # do
+            pl.BlockSpec((None, bq, 1), lambda h, j, i: (h, i, 0)),  # lse
+            pl.BlockSpec((None, bq, 1), lambda h, j, i: (h, i, 0)),  # delta
         ],
         out_specs=[
-            pl.BlockSpec((1, bk, D), lambda h, j, i: (h, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda h, j, i: (h, j, 0)),
+            pl.BlockSpec((None, bk, D), lambda h, j, i: (h, j, 0)),
+            pl.BlockSpec((None, bk, D), lambda h, j, i: (h, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BH, Sk, D), k.dtype),
@@ -142,14 +142,14 @@ def flash_attention_bwd_kernel(q, k, v, do, lse, delta, *, causal: bool,
                           q_offset=q_offset, n_kv=n_kv),
         grid=(BH, n_q, n_kv),
         in_specs=[
-            pl.BlockSpec((1, bq, D), lambda h, i, j: (h, i, 0)),  # q
-            pl.BlockSpec((1, bk, D), lambda h, i, j, G=G: (h // G, j, 0)),  # k
-            pl.BlockSpec((1, bk, D), lambda h, i, j, G=G: (h // G, j, 0)),  # v
-            pl.BlockSpec((1, bq, D), lambda h, i, j: (h, i, 0)),  # do
-            pl.BlockSpec((1, bq), lambda h, i, j: (h, i)),  # lse
-            pl.BlockSpec((1, bq), lambda h, i, j: (h, i)),  # delta
+            pl.BlockSpec((None, bq, D), lambda h, i, j: (h, i, 0)),  # q
+            pl.BlockSpec((None, bk, D), lambda h, i, j, G=G: (h // G, j, 0)),  # k
+            pl.BlockSpec((None, bk, D), lambda h, i, j, G=G: (h // G, j, 0)),  # v
+            pl.BlockSpec((None, bq, D), lambda h, i, j: (h, i, 0)),  # do
+            pl.BlockSpec((None, bq, 1), lambda h, i, j: (h, i, 0)),  # lse
+            pl.BlockSpec((None, bq, 1), lambda h, i, j: (h, i, 0)),  # delta
         ],
-        out_specs=pl.BlockSpec((1, bq, D), lambda h, i, j: (h, i, 0)),
+        out_specs=pl.BlockSpec((None, bq, D), lambda h, i, j: (h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, Sq, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         interpret=interpret,

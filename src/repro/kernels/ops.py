@@ -71,7 +71,7 @@ def _fat_bwd(causal, q_offset, res, g):
     KV = kf.shape[0] // B
     G = H // KV
     dof = _fold_q(g)
-    delta = jnp.sum(dof.astype(jnp.float32) * of.astype(jnp.float32), axis=-1)
+    delta = jnp.sum(dof.astype(jnp.float32) * of.astype(jnp.float32), axis=-1, keepdims=True)
     dq, dk_q, dv_q = flash_attention_bwd_kernel(
         qf, kf, vf, dof, lse, delta, causal=causal, q_offset=q_offset,
         interpret=_interpret(),
@@ -91,10 +91,14 @@ def decode_attention(q, k, v, kv_len, *, block_k=512):
     """q [B, H, D]; k, v [B, S, KV, D]; kv_len scalar -> [B, H, D]."""
     B, H, D = q.shape
     KV = k.shape[2]
-    qf = q.reshape(B * H, D)
+    qf = q.reshape(B * KV, H // KV, D)  # heads are kv-major: head h reads kv h // G
     kf = k.transpose(0, 2, 1, 3).reshape(B * KV, -1, D)
     vf = v.transpose(0, 2, 1, 3).reshape(B * KV, -1, D)
-    of = decode_attention_kernel(qf, kf, vf, kv_len, block_k=block_k, interpret=_interpret())
+    S = kf.shape[1]
+    bk = min(block_k, S)
+    while S % bk:
+        bk //= 2
+    of = decode_attention_kernel(qf, kf, vf, kv_len, block_k=bk, interpret=_interpret())
     return of.reshape(B, H, D)
 
 
@@ -129,7 +133,7 @@ def rglru_scan(a, g, *, block_s=256, block_m=512):
 
 
 @partial(jax.jit, static_argnames=("block_s", "block_c"))
-def mamba_scan(dA, dBu, C, *, block_s=128, block_c=512):
+def mamba_scan(dA, dBu, C, *, block_s=16, block_c=256):
     """dA, dBu [B, S, Ch, N]; C [B, S, N] -> y [B, S, Ch] (vmapped batch)."""
     bs = min(block_s, dA.shape[1])
     while dA.shape[1] % bs:

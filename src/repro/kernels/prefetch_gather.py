@@ -28,23 +28,27 @@ def _gather_kernel(idx_ref, table_ref, out_ref):
 
 
 def prefetch_gather_kernel(table, idx, *, block_d: int = 512, interpret: bool = True):
-    """table [N, D] (D % 128 == 0), idx [B] int32 -> out [B, D]."""
+    """table [N, D] (D % 128 == 0), idx [B] int32 -> out [B, D].
+
+    Rows travel as [1, block_d] tiles of a [N, 1, D] view, so the block's
+    last two dims are whole (1) and lane-aligned (block_d)."""
     N, D = table.shape
     (B,) = idx.shape
     block_d = min(block_d, D)
     assert D % block_d == 0 and block_d % LANE == 0, (D, block_d)
     grid = (B, D // block_d)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _gather_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, block_d), lambda b, j, idx_ref: (idx_ref[b], j)),
+                pl.BlockSpec((None, 1, block_d), lambda b, j, idx_ref: (idx_ref[b], 0, j)),
             ],
-            out_specs=pl.BlockSpec((1, block_d), lambda b, j, idx_ref: (b, j)),
+            out_specs=pl.BlockSpec((None, 1, block_d), lambda b, j, idx_ref: (b, 0, j)),
         ),
-        out_shape=jax.ShapeDtypeStruct((B, D), table.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, 1, D), table.dtype),
         interpret=interpret,
         name="prefetch_gather",
-    )(idx.astype(jnp.int32), table)
+    )(idx.astype(jnp.int32), table.reshape(N, 1, D))
+    return out.reshape(B, D)

@@ -9,6 +9,7 @@ Inputs are the precomputed per-step decay ``a`` and gated input ``g``
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -16,27 +17,26 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _rglru_kernel(a_ref, g_ref, y_ref, h_ref, *, bs: int):
+def _rglru_kernel(a_ref, g_ref, y_ref, h_ref, *, bs: int, rows: int):
     s_idx = pl.program_id(1)
 
     @pl.when(s_idx == 0)
     def _init():
         h_ref[...] = jnp.zeros_like(h_ref)
 
-    a = a_ref[...].astype(jnp.float32)  # [bs, bm]
-    g = g_ref[...].astype(jnp.float32)
+    def chunk(c, h):  # h [1, bm]; ``rows`` sequence steps per tile-aligned load
+        base = pl.multiple_of(c * rows, rows)
+        a = a_ref[pl.ds(base, rows), :].astype(jnp.float32)  # [rows, bm]
+        g = g_ref[pl.ds(base, rows), :].astype(jnp.float32)
+        row_id = jax.lax.broadcasted_iota(jnp.int32, a.shape, 0)
+        ys = jnp.zeros_like(a)
+        for r in range(rows):
+            h = a[r : r + 1] * h + g[r : r + 1]
+            ys = jnp.where(row_id == r, h, ys)
+        y_ref[pl.ds(base, rows), :] = ys.astype(y_ref.dtype)
+        return h
 
-    def step(t, carry):
-        h, ys = carry
-        h = a[t] * h + g[t]
-        ys = jax.lax.dynamic_update_index_in_dim(ys, h, t, 0)
-        return (h, ys)
-
-    h0 = h_ref[...]
-    ys0 = jnp.zeros(a.shape, jnp.float32)
-    h, ys = jax.lax.fori_loop(0, bs, step, (h0, ys0))
-    h_ref[...] = h
-    y_ref[...] = ys.astype(y_ref.dtype)
+    h_ref[...] = jax.lax.fori_loop(0, bs // rows, chunk, h_ref[...])
 
 
 def rglru_scan_kernel(a, g, *, block_s: int = 256, block_m: int = 512,
@@ -46,7 +46,9 @@ def rglru_scan_kernel(a, g, *, block_s: int = 256, block_m: int = 512,
     bs, bm = min(block_s, S), min(block_m, M)
     assert S % bs == 0 and M % bm == 0
     grid = (M // bm, S // bs)  # sequence innermost (sequential)
-    kernel = functools.partial(_rglru_kernel, bs=bs)
+    # rows per load: one sublane tile of the input dtype (8 f32 / 16 bf16)
+    rows = math.gcd(bs, 32 // jnp.dtype(a.dtype).itemsize)
+    kernel = functools.partial(_rglru_kernel, bs=bs, rows=rows)
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -56,7 +58,7 @@ def rglru_scan_kernel(a, g, *, block_s: int = 256, block_m: int = 512,
         ],
         out_specs=pl.BlockSpec((bs, bm), lambda m, s: (s, m)),
         out_shape=jax.ShapeDtypeStruct((S, M), a.dtype),
-        scratch_shapes=[pltpu.VMEM((bm,), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, bm), jnp.float32)],
         interpret=interpret,
         name="rglru_scan",
     )(a, g)

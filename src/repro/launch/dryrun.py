@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (architecture x input-shape)
 cell on the production meshes (single-pod 16x16 = 256 chips; multi-pod
 2x16x16 = 512 chips), proving the distribution config is coherent, and
@@ -15,6 +12,10 @@ record the roofline inputs per cell:
 Artifacts land in artifacts/dryrun/<arch>__<shape>__<mesh>.json and are
 consumed by benchmarks/roofline.py and EXPERIMENTS.md.
 
+The dry run compiles for 512 virtual CPU devices and never takes an
+accelerator: ``main()`` pins itself and its ``--all`` children to
+``JAX_PLATFORMS=cpu`` before JAX is imported.
+
 Usage:
   python -m repro.launch.dryrun --arch granite_moe_1b_a400m --shape train_4k --mesh single
   python -m repro.launch.dryrun --all [--mesh both] [--jobs 2]
@@ -22,6 +23,7 @@ Usage:
 
 import argparse
 import json
+import os
 import subprocess
 import sys
 import time
@@ -200,6 +202,9 @@ def iter_cells():
 
 
 def main() -> int:
+    # before the first JAX import; the --all children inherit both
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    os.environ["JAX_PLATFORMS"] = "cpu"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
     ap.add_argument("--shape")

@@ -19,8 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .compat import shard_map
-
 
 def gpipe(
     stage_fn: Callable,
@@ -71,11 +69,12 @@ def gpipe(
 
     def run(stage_params, microbatches):
         specs_params = jax.tree.map(lambda _: P(axis), stage_params)
-        return shard_map(
+        return jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(specs_params, P()),
             out_specs=P(),
+            check_vma=False,
         )(stage_params, microbatches)
 
     return run

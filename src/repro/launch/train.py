@@ -24,6 +24,7 @@ from repro.data import DataPipeline, SyntheticLMSource
 from repro.models.common import activate_sharding
 from repro.runtime.fault import StragglerDetector
 
+from .compile_cache import setup_compile_cache
 from .mesh import data_axes
 from .shardings import batch_pspecs, logical_rules, named
 from .steps import make_optimizer, make_train_step
@@ -146,6 +147,7 @@ def main() -> None:
     ap.add_argument("--save-every", type=int, default=100)
     args = ap.parse_args()
 
+    setup_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     trainer = Trainer(
         cfg, mesh=None, global_batch=args.batch, seq_len=args.seq,

@@ -154,10 +154,8 @@ def moe_apply_ep(x, p, cfg, compute_dtype, mesh, data_axes, model_axis: str):
         y = jax.lax.psum(y, model_axis)
         return y.reshape(Bl, S, d)
 
-    from repro.launch.compat import shard_map
-
     dspec = P(data_axes, None, None)
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -168,6 +166,7 @@ def moe_apply_ep(x, p, cfg, compute_dtype, mesh, data_axes, model_axis: str):
             P(model_axis, None, None),
         ),
         out_specs=dspec,
+        check_vma=False,
     )(x, p["router"], p["we_gate"], p["we_up"], p["we_down"])
 
 
@@ -176,8 +175,6 @@ def moe_apply_fsdp(x, p, cfg, compute_dtype, mesh, batch_axes):
     arrive via the shard_map replication gather (the per-layer FSDP weight
     all-gather) and every device runs the full dense dispatch on its local
     tokens — routing/dispatch math is entirely collective-free."""
-    from repro.launch.compat import shard_map
-
     def body(xl, router_w, wg, wu, wd):
         Bl, S, d = xl.shape
         y = _route_dispatch_ffn(
@@ -187,10 +184,10 @@ def moe_apply_fsdp(x, p, cfg, compute_dtype, mesh, batch_axes):
 
     bspec = P(batch_axes, None, None)
     rep2, rep3 = P(None, None), P(None, None, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(bspec, rep2, rep3, rep3, rep3),
-        out_specs=bspec,
+        out_specs=bspec, check_vma=False,
     )(x, p["router"], p["we_gate"], p["we_up"], p["we_down"])
 
 
@@ -200,8 +197,6 @@ def moe_apply_ep_a2a(x, p, cfg, compute_dtype, mesh, batch_axes, model_axis):
     dispatch matmul, no replication) and exchanges capacity buffers with the
     expert shards via all-to-all over ``model``.  Collective payload is the
     [E, C_local, d] activation buffer — independent of the expert bank size."""
-    from repro.launch.compat import shard_map
-
     E, k = cfg.n_experts, cfg.experts_per_token
     n_model = mesh.shape[model_axis]
     E_local = E // n_model
@@ -227,11 +222,11 @@ def moe_apply_ep_a2a(x, p, cfg, compute_dtype, mesh, batch_axes, model_axis):
         return y.reshape(Bl, S, d)
 
     bspec = P(batch_axes, None, None)
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(bspec, P(None, None), P(model_axis, None, None),
                   P(model_axis, None, None), P(model_axis, None, None)),
-        out_specs=bspec,
+        out_specs=bspec, check_vma=False,
     )(x, p["router"], p["we_gate"], p["we_up"], p["we_down"])
     # under remat="dots_collectives" the saved name keeps the backward from
     # re-running the all-to-alls (collectives are the scarce resource)

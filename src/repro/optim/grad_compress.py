@@ -70,18 +70,17 @@ def make_compressed_allreduce(mesh, axis: str = "pod"):
     """Returns fn(grads, residuals) -> (mean grads, residuals) running the
     compressed reduction over the given mesh axis via shard_map; other axes
     untouched (their reductions happen inside the step as usual)."""
-    from repro.launch.compat import shard_map
-
     def fn(grads, residuals):
         specs = jax.tree.map(lambda _: P(), grads)
 
         def body(g, r):
             return compressed_psum_tree(g, r, axis)
 
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(specs, specs),
             out_specs=(specs, specs),
+            check_vma=False,
         )(grads, residuals)
 
     return fn

@@ -57,6 +57,23 @@ def test_flash_attention_q_offset_decode_chunk():
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("B,S,H,KV,D", [(1, 128, 2, 2, 64), (2, 256, 4, 2, 64)])
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_attention_grads_match_ref(B, S, H, KV, D, causal):
+    """The flash backward kernels (dk/dv and dq) give the oracle's
+    gradients, GQA group sums included."""
+    rng = np.random.RandomState(7)
+    q, k, v = (jnp.asarray(rng.randn(B, S, n, D), jnp.float32) for n in (H, KV, KV))
+    w = jnp.asarray(rng.randn(B, S, H, D), jnp.float32)
+    loss = lambda f: lambda q, k, v: jnp.sum(f(q, k, v) * w)
+    got = jax.grad(loss(lambda q, k, v: ops.flash_attention_trainable(q, k, v, causal)),
+                   argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda q, k, v: ref.flash_attention_ref(q, k, v, causal=causal)),
+                    argnums=(0, 1, 2))(q, k, v)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=1e-4, atol=1e-4)
+
+
 # ---------------------------------------------------------------------------
 # decode attention
 # ---------------------------------------------------------------------------

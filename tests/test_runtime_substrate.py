@@ -280,9 +280,8 @@ def test_compressed_allreduce_in_shard_map():
 
 
 def test_compat_shard_map_runs_two_device_psum():
-    """The compat shim must resolve shard_map on whichever jax generation is
-    installed (jax.shard_map + check_vma on >= 0.6, the experimental import
-    + check_rep before) — this is the regression test for the shim itself,
+    """``jax.shard_map`` with ``check_vma=False`` (the spelling every
+    shard_map call site in the package uses) runs a psum over two devices,
     independent of any model code built on top of it."""
     import subprocess, sys, textwrap, os
     from pathlib import Path
@@ -290,11 +289,10 @@ def test_compat_shard_map_runs_two_device_psum():
     code = textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        from repro.launch.compat import shard_map
-
         mesh = Mesh(np.asarray(jax.devices()).reshape(2,), ("x",))
-        f = shard_map(
-            lambda a: jax.lax.psum(a, "x"), mesh, in_specs=(P("x"),), out_specs=P()
+        f = jax.shard_map(
+            lambda a: jax.lax.psum(a, "x"), mesh=mesh, in_specs=(P("x"),),
+            out_specs=P(), check_vma=False,
         )
         out = f(jnp.arange(4.0))
         np.testing.assert_allclose(np.asarray(out), [2.0, 4.0])
