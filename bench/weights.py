@@ -1,0 +1,148 @@
+"""Weights of a dense decoder, drawn on the device from the run's seed.
+
+``make_params`` builds the whole served tree in one jitted program, in the
+served dtype and in the program's layout (stacked layers).  ``layer`` and
+``top`` draw the same values again, one layer at a time, so that the
+reference can rebuild them after the served copy is freed.  Every value is
+a function of (seed, leaf, layer) alone: the same seed gives the same
+weights in either form.
+
+Scales keep a 40-layer random stack well conditioned: unit-variance
+embeddings, 1/sqrt(fan_in) projections, norms and biases drawn away from
+their trivial values (1 and 0) so that a wrong or missing one shows.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+# (name, init, scale): "normal" draws scale * N(0, 1); "around_one" draws
+# 1 + scale * N(0, 1).  A scale of None means 1/sqrt(fan_in).
+_LAYER = (
+    ("ln1", "around_one", 0.1),
+    ("ln2", "around_one", 0.1),
+    ("attn.wq", "normal", None),
+    ("attn.wk", "normal", None),
+    ("attn.wv", "normal", None),
+    ("attn.wo", "normal", None),
+    ("attn.bq", "normal", 0.1),
+    ("attn.bk", "normal", 0.1),
+    ("attn.bv", "normal", 0.1),
+    ("mlp.wi_gate", "normal", None),
+    ("mlp.wi_up", "normal", None),
+    ("mlp.wo", "normal", None),
+)
+_TOP = (
+    ("embed", "normal", 1.0),
+    ("lm_head", "normal", None),
+    ("final_norm", "around_one", 0.1),
+)
+
+
+def layer_shapes(m: dict) -> dict:
+    d, f = m["d_model"], m["d_ff"]
+    q, kv = m["n_heads"] * m["head_dim"], m["n_kv_heads"] * m["head_dim"]
+    shapes = {
+        "ln1": (d,), "ln2": (d,),
+        "attn.wq": (d, q), "attn.wk": (d, kv), "attn.wv": (d, kv), "attn.wo": (q, d),
+        "mlp.wi_gate": (d, f), "mlp.wi_up": (d, f), "mlp.wo": (f, d),
+    }
+    if m["qkv_bias"]:
+        shapes.update({"attn.bq": (q,), "attn.bk": (kv,), "attn.bv": (kv,)})
+    return shapes
+
+
+def top_shapes(m: dict, vocab_rows: int) -> dict:
+    """``vocab_rows``: the embedding rows the program holds (its vocab
+    padded); rows past ``vocab_size`` are drawn but never read."""
+    d = m["d_model"]
+    return {"embed": (vocab_rows, d), "lm_head": (d, vocab_rows), "final_norm": (d,)}
+
+
+def _draw(key, spec, shape, dtype):
+    _, init, scale = spec
+    if scale is None:
+        scale = shape[0] ** -0.5
+    x = jax.random.normal(key, shape, jnp.float32) * scale
+    if init == "around_one":
+        x = 1.0 + x
+    return x.astype(dtype)
+
+
+def _nest(flat: dict) -> dict:
+    out: dict = {}
+    for name, x in flat.items():
+        *path, leaf = name.split(".")
+        node = out
+        for p in path:
+            node = node.setdefault(p, {})
+        node[leaf] = x
+    return out
+
+
+def _draw_all(key, specs, shapes, dtype) -> dict:
+    return _nest({
+        spec[0]: _draw(jax.random.fold_in(key, i), spec, shapes[spec[0]], dtype)
+        for i, spec in enumerate(specs) if spec[0] in shapes
+    })
+
+
+def root_key(seed: int):
+    """A key for any whole number up to 2**63: ``jax.random.key`` keeps only
+    the low 32 bits, so the high ones are folded in."""
+    return _root(jnp.uint32(seed & 0xFFFFFFFF), jnp.uint32((seed >> 32) & 0xFFFFFFFF))
+
+
+@jax.jit
+def _root(lo, hi):
+    return jax.random.key_data(jax.random.fold_in(jax.random.key(lo), hi))
+
+
+def _key(data):
+    return jax.random.wrap_key_data(data)
+
+
+def layer(m: dict, key_data, index: int, dtype):
+    """One layer's weights, nested as the program holds them."""
+    return _layer(_freeze(m), key_data, jnp.uint32(index), jnp.dtype(dtype))
+
+
+def top(m: dict, key_data, vocab_rows: int, dtype):
+    return _top(_freeze(m), key_data, vocab_rows, jnp.dtype(dtype))
+
+
+def make_params(m: dict, key_data, vocab_rows: int, dtype):
+    """The whole served tree in one program: layers drawn one at a time
+    (``lax.map``), so no leaf is ever held whole in float32."""
+    return _params(_freeze(m), key_data, vocab_rows, jnp.dtype(dtype))
+
+
+def _freeze(m: dict):
+    return tuple(sorted(m.items()))
+
+
+def _layer_body(mf, key_data, index, dtype):
+    m = dict(mf)
+    key = jax.random.fold_in(jax.random.fold_in(_key(key_data), 1), index)
+    return _draw_all(key, _LAYER, layer_shapes(m), dtype)
+
+
+def _top_body(mf, key_data, vocab_rows, dtype):
+    key = jax.random.fold_in(_key(key_data), 0)
+    return _draw_all(key, _TOP, top_shapes(dict(mf), vocab_rows), dtype)
+
+
+_layer = jax.jit(_layer_body, static_argnums=(0, 3))
+_top = jax.jit(_top_body, static_argnums=(0, 2, 3))
+
+
+def _params_body(mf, key_data, vocab_rows, dtype):
+    n = dict(mf)["n_layers"]
+    tree = _top_body(mf, key_data, vocab_rows, dtype)
+    tree["layers"] = jax.lax.map(
+        lambda i: _layer_body(mf, key_data, i, dtype), jnp.arange(n, dtype=jnp.uint32))
+    return tree
+
+
+_params = jax.jit(_params_body, static_argnums=(0, 2, 3))

@@ -1,0 +1,70 @@
+"""The benchmark's plain reference (bench/reference/dense.py) against the
+program's model at both configurations' smoke sizes, in float32 on the CPU:
+the prefill's last logits, then decoding through the cache, teacher-forced
+on fixed tokens.  Same weights (bench/weights.py) on both sides."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import tinycell  # noqa: F401  (puts the repository on sys.path)
+from bench import weights
+from bench.reference import dense
+from repro.configs import get_smoke_config
+from repro.models.model import Model
+
+P, STEPS, N = 12, 5, 3
+
+
+@pytest.mark.parametrize("name", sorted(tinycell.TINY))
+def test_reference_matches_program_prefill_and_decode(name):
+    spec = tinycell.TINY[name]
+    m = {**spec["model"], "dtype": "float32"}
+    cfg = get_smoke_config(spec["program_config"]).replace(
+        compute_dtype="float32", param_dtype="float32")
+    model = Model(cfg)
+    rows = model.abstract_params()["embed"].shape[0]
+    key = weights.root_key(2**35 + 3)
+    params = weights.make_params(m, key, rows, "float32")
+    tokens = np.random.default_rng(0).integers(0, m["vocab_size"], (N, P + STEPS), np.int32)
+
+    want = np.asarray(dense.logits(m, key, rows, tokens, P - 1))  # [N, STEPS + 1, V]
+    logits, cache = model.prefill(params, {"inputs": jnp.asarray(tokens[:, :P])})
+    got = [logits[:, -1]]
+    cache = {k: jnp.pad(v, ((0, 0), (0, 0), (0, STEPS), (0, 0), (0, 0))) for k, v in cache.items()}
+    for i in range(STEPS):
+        logits, cache = model.decode_step(params, cache, jnp.asarray(tokens[:, P + i : P + i + 1]),
+                                          P + i)
+        got.append(logits[:, -1])
+    got = np.stack([np.asarray(g) for g in got], axis=1)
+    scale = np.abs(want).max()
+    assert scale > 0.5  # logits spread, not a degenerate model
+    np.testing.assert_allclose(got, want, atol=2e-4 * scale, rtol=0)
+
+
+def test_layer_by_layer_weights_equal_the_served_tree():
+    m = tinycell.TINY["tiny-chatglm"]["model"]
+    key = weights.root_key(7)
+    tree = weights.make_params(m, key, 128, m["dtype"])
+    for i in range(m["n_layers"]):
+        one = weights.layer(m, key, i, m["dtype"])
+        jax.tree.map(lambda a, b: np.testing.assert_array_equal(a[i], b), tree["layers"], one)
+    top = weights.top(m, key, 128, m["dtype"])
+    for k in top:
+        np.testing.assert_array_equal(tree[k], top[k])
+
+
+def test_seeds_past_32_bits_differ():
+    a, b = weights.root_key(5), weights.root_key(5 + 2**32)
+    assert not np.array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_fp8_control_departs_from_the_reference():
+    m = tinycell.TINY["tiny-qwen"]["model"]
+    key = weights.root_key(11)
+    tokens = np.random.default_rng(1).integers(0, m["vocab_size"], (2, 16), np.int32)
+    f32 = np.asarray(dense.logits(m, key, 128, tokens, 8))
+    f8 = np.asarray(dense.logits(m, key, 128, tokens, 8, quant="fp8"))
+    err = np.abs(f8 - f32).max() / np.abs(f32).max()
+    assert 1e-3 < err < 0.5
