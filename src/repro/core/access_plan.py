@@ -93,6 +93,13 @@ def build_access_plan(fn, params, *args, **kwargs) -> PrefetchPlan:
     ``params`` may be concrete arrays or ShapeDtypeStructs (no allocation
     needed — same property as the paper's compile-time analysis)."""
     closed = jax.make_jaxpr(lambda p, *a: fn(p, *a, **kwargs))(params, *args)
+    return plan_from_jaxpr(closed, params)
+
+
+def plan_from_jaxpr(closed, params) -> PrefetchPlan:
+    """The access plan from a step already traced to ``closed`` (a
+    ``ClosedJaxpr`` whose leading inputs are ``params``' leaves): the walk
+    half of :func:`build_access_plan`."""
     jaxpr = closed.jaxpr
 
     leaves, _ = jax.tree_util.tree_flatten_with_path(params)

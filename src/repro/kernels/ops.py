@@ -13,6 +13,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.obs import scopes
+
 from .decode_attention import decode_attention_kernel
 from .flash_attention import flash_attention_kernel
 from .mamba_scan import mamba_scan_kernel
@@ -26,12 +28,14 @@ def _interpret() -> bool:
 
 def _fold_q(q):
     B, Sq, H, D = q.shape
-    return q.transpose(0, 2, 1, 3).reshape(B * H, Sq, D)
+    with scopes.scope("kv_layout"):
+        return q.transpose(0, 2, 1, 3).reshape(B * H, Sq, D)
 
 
 def _unfold_q(qf, B, H):
     BH, Sq, D = qf.shape
-    return qf.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
+    with scopes.scope("kv_layout"):
+        return qf.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
 
 
 @partial(jax.jit, static_argnames=("causal", "q_offset", "block_q", "block_k"))
@@ -91,9 +95,10 @@ def decode_attention(q, k, v, kv_len, *, block_k=512):
     """q [B, H, D]; k, v [B, S, KV, D]; kv_len scalar -> [B, H, D]."""
     B, H, D = q.shape
     KV = k.shape[2]
-    qf = q.reshape(B * KV, H // KV, D)  # heads are kv-major: head h reads kv h // G
-    kf = k.transpose(0, 2, 1, 3).reshape(B * KV, -1, D)
-    vf = v.transpose(0, 2, 1, 3).reshape(B * KV, -1, D)
+    with scopes.scope("kv_layout"):
+        qf = q.reshape(B * KV, H // KV, D)  # heads are kv-major: head h reads kv h // G
+        kf = k.transpose(0, 2, 1, 3).reshape(B * KV, -1, D)
+        vf = v.transpose(0, 2, 1, 3).reshape(B * KV, -1, D)
     S = kf.shape[1]
     bk = min(block_k, S)
     while S % bk:

@@ -10,18 +10,50 @@ Usage (CPU-scale example):
 from __future__ import annotations
 
 import argparse
+import functools
 import time
+import weakref
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config, get_smoke_config
-from repro.core.access_plan import build_access_plan
+from repro.core.access_plan import plan_from_jaxpr
 from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.steps import concrete_batch, make_decode_step, make_prefill_step
+from repro.obs import Registry, scopes
 
 
 KV_BLOCK = 128  # the cache length is padded to this (the decode kernel's tile)
+
+# JAX's compile events, by the stage ``serve.compiles`` counts them under
+COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+_compile_counts: "weakref.WeakSet[Registry]" = weakref.WeakSet()  # live Servers' stats
+
+
+def _on_compile_event(event: str, _secs: float, fun_name: str = "", **_kw) -> None:
+    stage = COMPILE_EVENTS.get(event)
+    if stage is None:
+        return
+    # a compile names its program ``jit(prefill)``, a trace the function
+    fn = fun_name[4:-1] if fun_name.startswith("jit(") and fun_name.endswith(")") else fun_name
+    for stats in list(_compile_counts):
+        stats.counter("serve.compiles", fn=fn, stage=stage).inc()
+
+
+@functools.cache
+def _listen() -> None:
+    jax.monitoring.register_event_duration_secs_listener(_on_compile_event)
+
+
+def _count_compiles(stats: Registry) -> None:
+    """Count every trace and compile in the process into ``stats`` while it
+    lives, from one ``jax.monitoring`` listener shared by all Servers."""
+    _listen()
+    _compile_counts.add(stats)
 
 
 def pad_cache(cfg, cache: dict, max_len: int) -> dict:
@@ -29,9 +61,25 @@ def pad_cache(cfg, cache: dict, max_len: int) -> dict:
     if cfg.family in ("dense", "moe", "encdec"):
         pad = max_len - cache["k"].shape[2]
         if pad > 0:
-            for key in ("k", "v"):
-                cache[key] = jnp.pad(cache[key], ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
+            with scopes.scope("kv_write"):
+                for key in ("k", "v"):
+                    cache[key] = jnp.pad(cache[key], ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
     return cache
+
+
+class Step:
+    """A jitted step that counts its calls and runs each inside the profiler
+    span ``span``: on a trace's host plane, on the device ops' clock, it
+    names what the host was doing.  ``lower`` is the jitted function's."""
+
+    def __init__(self, fn, span: str, calls):
+        self._fn, self._span, self._calls = fn, span, calls
+        self.lower = fn.lower
+
+    def __call__(self, *args):
+        self._calls.inc()
+        with jax.profiler.TraceAnnotation(self._span):
+            return self._fn(*args)
 
 
 class Server:
@@ -39,7 +87,14 @@ class Server:
     published configs: no per-step cast copies of the weights).  On a TPU
     the attention runs the Pallas kernels; the cache length is rounded up
     to a multiple of ``KV_BLOCK`` so the decode kernel can tile it (slots
-    past the written prefix are masked by position)."""
+    past the written prefix are masked by position).
+
+    ``stats`` (a ``repro.obs.Registry``): counters ``serve.steps{kind}``
+    (calls of ``prefill`` and ``decode``) and ``serve.compiles{fn, stage}``
+    (every jaxpr trace and backend compile in the process while the Server
+    lives, by function name), gauges ``serve.plan_trace_s`` and
+    ``serve.plan_walk_s`` (the last ``plan``: tracing the decode step, and
+    walking its jaxpr)."""
 
     def __init__(self, cfg, mesh=None, max_len: int = 256):
         cfg = cfg.replace(param_dtype=cfg.compute_dtype)
@@ -55,18 +110,26 @@ class Server:
             logits, cache = self.prefill_fn(params, batch)
             return logits, pad_cache(cfg, cache, self.max_len)
 
-        self.prefill = jax.jit(prefill)
-        self.decode = jax.jit(self.decode_fn, donate_argnums=(1,))
+        self.stats = Registry()
+        _count_compiles(self.stats)
+        steps = lambda kind: self.stats.counter("serve.steps", kind=kind)
+        self.prefill = Step(jax.jit(prefill), "serve.prefill", steps("prefill"))
+        self.decode = Step(jax.jit(self.decode_fn, donate_argnums=(1,)), "serve.decode",
+                           steps("decode"))
 
     def plan(self, batch_size: int):
         """The CAPre access plan of one decode step (compile-time, no
         allocation)."""
-        return build_access_plan(
-            lambda p, c, t: self.decode_fn(p, c, t, 0),
-            self.model.abstract_params(),
-            self.model.abstract_cache(batch_size, self.max_len),
-            jax.ShapeDtypeStruct((batch_size, 1), jnp.int32),
-        )
+        params = self.model.abstract_params()
+        t0 = time.perf_counter()
+        closed = jax.make_jaxpr(lambda p, c, t: self.decode_fn(p, c, t, 0))(
+            params, self.model.abstract_cache(batch_size, self.max_len),
+            jax.ShapeDtypeStruct((batch_size, 1), jnp.int32))
+        t1 = time.perf_counter()
+        plan = plan_from_jaxpr(closed, params)
+        self.stats.gauge("serve.plan_trace_s").set(t1 - t0)
+        self.stats.gauge("serve.plan_walk_s").set(time.perf_counter() - t1)
+        return plan
 
     def compile(self, params, batch: dict) -> dict:
         """Compile both steps for this batch's shapes ahead of the first

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.obs import scopes
 
 from .common import (
     ParamSpec,
@@ -240,8 +241,9 @@ class Model:
 
     def embed(self, params, tokens):
         dt = cfg_dtype(self.cfg)
-        x = jnp.take(params["embed"], tokens, axis=0).astype(dt)
-        return constrain(x, "batch", "seq", "embed")
+        with scopes.scope("embed"):
+            x = jnp.take(params["embed"], tokens, axis=0).astype(dt)
+            return constrain(x, "batch", "seq", "embed")
 
     def logits(self, params, h):
         cfg = self.cfg
@@ -256,7 +258,8 @@ class Model:
     def _final_norm(self, params, h):
         from .layers import apply_norm
 
-        return apply_norm(self.cfg.norm, h, params["final_norm"], params.get("final_norm_b"))
+        with scopes.scope("logits"):
+            return apply_norm(self.cfg.norm, h, params["final_norm"], params.get("final_norm_b"))
 
     # -- full-sequence forward -------------------------------------------------
 
@@ -328,8 +331,10 @@ class Model:
         """Full forward; returns (last-token logits, decode cache)."""
         cfg = self.cfg
         h, extras = self.hidden_states(params, batch, mesh_info, collect_cache=True)
-        logits = self.logits(params, h[:, -1:, :])[..., : cfg.vocab_size]
-        cache = self._assemble_cache(batch, extras)
+        with scopes.scope("logits"):
+            logits = self.logits(params, h[:, -1:, :])[..., : cfg.vocab_size]
+        with scopes.scope("kv_write"):
+            cache = self._assemble_cache(batch, extras)
         return logits, cache
 
     def _assemble_cache(self, batch, extras):
@@ -384,7 +389,8 @@ class Model:
         else:
             raise ValueError(cfg.family)
         h = self._final_norm(params, h)
-        return self.logits(params, h)[..., : cfg.vocab_size], cache
+        with scopes.scope("logits"):
+            return self.logits(params, h)[..., : cfg.vocab_size], cache
 
     # -- cache templates (for the decode dry-run input specs) -------------------
 

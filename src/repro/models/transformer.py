@@ -15,6 +15,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.obs import scopes
+
 from .common import constrain
 from .layers import (
     apply_norm,
@@ -51,37 +53,42 @@ def _remat(fn, cfg):
 def attn_block(x, lp, cfg, dt, positions, *, causal=True, local_window=0,
                cross_kv=None, collect_cache=False):
     """Pre-norm attention sub-block. Returns (x, (k, v) or None)."""
-    h = apply_norm(cfg.norm, x, lp["ln1"], lp.get("ln1_b"))
-    # pin the (bf16) norm output to the residual layout so under SP the
-    # all-gather crosses in bf16, not the norm's f32 internals (§Perf It3)
-    h = constrain(h, "batch", "seq", "embed")
-    q, k, v = qkv_project(h, lp["attn"], cfg, dt)
-    if cross_kv is None:
-        q = apply_rope(cfg.rope, q, positions, cfg.rope_theta)
-        k = apply_rope(cfg.rope, k, positions, cfg.rope_theta)
-    else:
-        k, v = cross_kv
-        causal = False
-    q = constrain(q, "batch", "inner_seq", "act_heads", None)
-    k = constrain(k, "batch", "inner_seq", "act_kv", None)
-    o = gqa_attention(
-        q, k, v, causal=causal, impl=cfg.attn_impl, chunk=cfg.attn_chunk,
-        local_window=local_window,
-    )
-    x = x + attn_output(o, lp["attn"], cfg, dt)
-    x = constrain(x, "batch", "seq", "embed")
+    with scopes.scope("qkv"):
+        h = apply_norm(cfg.norm, x, lp["ln1"], lp.get("ln1_b"))
+        # pin the (bf16) norm output to the residual layout so under SP the
+        # all-gather crosses in bf16, not the norm's f32 internals (§Perf It3)
+        h = constrain(h, "batch", "seq", "embed")
+        q, k, v = qkv_project(h, lp["attn"], cfg, dt)
+        if cross_kv is None:
+            q = apply_rope(cfg.rope, q, positions, cfg.rope_theta)
+            k = apply_rope(cfg.rope, k, positions, cfg.rope_theta)
+        else:
+            k, v = cross_kv
+            causal = False
+        q = constrain(q, "batch", "inner_seq", "act_heads", None)
+        k = constrain(k, "batch", "inner_seq", "act_kv", None)
+    with scopes.scope("attn_core"):
+        o = gqa_attention(
+            q, k, v, causal=causal, impl=cfg.attn_impl, chunk=cfg.attn_chunk,
+            local_window=local_window,
+        )
+    # the residual add inside the scope: a fusion takes its root's metadata
+    with scopes.scope("attn_out"):
+        x = x + attn_output(o, lp["attn"], cfg, dt)
+        x = constrain(x, "batch", "seq", "embed")
     return x, ((k, v) if collect_cache else None)
 
 
 def ffn_block(x, lp, cfg, dt, mesh_info):
-    h = apply_norm(cfg.norm, x, lp["ln2"], lp.get("ln2_b"))
-    h = constrain(h, "batch", "seq", "embed")
-    if cfg.family == "moe":
-        y = moe_apply(h, lp["mlp"], cfg, dt, mesh_info)
-    else:
-        y = mlp_apply(cfg.mlp, h, lp["mlp"], dt)
-    x = x + y
-    return constrain(x, "batch", "seq", "embed")
+    with scopes.scope("ffn"):
+        h = apply_norm(cfg.norm, x, lp["ln2"], lp.get("ln2_b"))
+        h = constrain(h, "batch", "seq", "embed")
+        if cfg.family == "moe":
+            y = moe_apply(h, lp["mlp"], cfg, dt, mesh_info)
+        else:
+            y = mlp_apply(cfg.mlp, h, lp["mlp"], dt)
+        x = x + y
+        return constrain(x, "batch", "seq", "embed")
 
 
 def dense_layer(x, lp, cfg, dt, positions, mesh_info, *, causal=True,
@@ -135,7 +142,8 @@ def forward_stack(params, cfg, x, positions, mesh_info, *, causal=True,
             ),
             cfg,
         )
-    x, extras = scan_stack(x, params["layers"], body)
+    with scopes.scope("layers"):
+        x, extras = scan_stack(x, params["layers"], body)
     return x, extras
 
 
@@ -225,35 +233,39 @@ def _decode_attn(x, lp, cfg, dt, k_cache, v_cache, pos, *, window: int = 0):
     """One-token attention against a cache [B, S, KV, hd]; writes the new
     k/v at ``pos`` (or ``pos % window`` for ring caches) and attends."""
     B = x.shape[0]
-    h = apply_norm(cfg.norm, x, lp["ln1"], lp.get("ln1_b"))
-    positions = jnp.full((B, 1), pos, jnp.int32)
-    if cfg.rope == "mrope":
-        positions = jnp.broadcast_to(positions, (3, B, 1))
-    q, k, v = qkv_project(h, lp["attn"], cfg, dt)
-    q = apply_rope(cfg.rope, q, positions, cfg.rope_theta)
-    k = apply_rope(cfg.rope, k, positions, cfg.rope_theta)
+    with scopes.scope("qkv"):
+        h = apply_norm(cfg.norm, x, lp["ln1"], lp.get("ln1_b"))
+        positions = jnp.full((B, 1), pos, jnp.int32)
+        if cfg.rope == "mrope":
+            positions = jnp.broadcast_to(positions, (3, B, 1))
+        q, k, v = qkv_project(h, lp["attn"], cfg, dt)
+        q = apply_rope(cfg.rope, q, positions, cfg.rope_theta)
+        k = apply_rope(cfg.rope, k, positions, cfg.rope_theta)
     slot = pos % window if window else pos
-    k_cache = jax.lax.dynamic_update_slice(k_cache, k.astype(k_cache.dtype), (0, slot, 0, 0))
-    v_cache = jax.lax.dynamic_update_slice(v_cache, v.astype(v_cache.dtype), (0, slot, 0, 0))
-    if window:
-        # ring buffer: mask by stored-position recency
-        S = k_cache.shape[1]
-        idx = jnp.arange(S)
-        ring_pos = pos - ((slot - idx) % S)  # absolute position stored per slot
-        valid = (ring_pos >= 0) & (ring_pos >= pos - window + 1)
-        o = _masked_decode_attention(q, k_cache, v_cache, valid, cfg)
-    elif cfg.attn_impl == "pallas" and k_cache.shape[1] % 128 == 0:
-        # flash-decode kernel: the KV cache streams HBM->VMEM once (in its
-        # stored dtype — fp8 caches halve the traffic), scores stay in VMEM
-        from repro.kernels.ops import decode_attention as _dec
+    with scopes.scope("kv_write"):
+        k_cache = jax.lax.dynamic_update_slice(k_cache, k.astype(k_cache.dtype), (0, slot, 0, 0))
+        v_cache = jax.lax.dynamic_update_slice(v_cache, v.astype(v_cache.dtype), (0, slot, 0, 0))
+    with scopes.scope("attn_core"):
+        if window:
+            # ring buffer: mask by stored-position recency
+            S = k_cache.shape[1]
+            idx = jnp.arange(S)
+            ring_pos = pos - ((slot - idx) % S)  # absolute position stored per slot
+            valid = (ring_pos >= 0) & (ring_pos >= pos - window + 1)
+            o = _masked_decode_attention(q, k_cache, v_cache, valid, cfg)
+        elif cfg.attn_impl == "pallas" and k_cache.shape[1] % 128 == 0:
+            # flash-decode kernel: the KV cache streams HBM->VMEM once (in its
+            # stored dtype — fp8 caches halve the traffic), scores stay in VMEM
+            from repro.kernels.ops import decode_attention as _dec
 
-        o = _dec(q[:, 0], k_cache, v_cache, pos + 1).astype(dt)[:, None]
-    else:
-        o = gqa_attention(
-            q, k_cache.astype(dt), v_cache.astype(dt), causal=False,
-            impl="naive", q_offset=pos, kv_len=pos + 1,
-        )
-    x = x + attn_output(o, lp["attn"], cfg, dt)
+            o = _dec(q[:, 0], k_cache, v_cache, pos + 1).astype(dt)[:, None]
+        else:
+            o = gqa_attention(
+                q, k_cache.astype(dt), v_cache.astype(dt), causal=False,
+                impl="naive", q_offset=pos, kv_len=pos + 1,
+            )
+    with scopes.scope("attn_out"):
+        x = x + attn_output(o, lp["attn"], cfg, dt)
     return x, k_cache, v_cache
 
 
@@ -280,7 +292,8 @@ def decode_stack(params, cfg, x, cache, pos, mesh_info):
         h = ffn_block(h, lp, cfg, dt, mesh_info)
         return h, (kc, vc)
 
-    x, (k_new, v_new) = jax.lax.scan(body, x, (params["layers"], cache["k"], cache["v"]))
+    with scopes.scope("layers"):
+        x, (k_new, v_new) = jax.lax.scan(body, x, (params["layers"], cache["k"], cache["v"]))
     return x, {"k": k_new, "v": v_new}
 
 

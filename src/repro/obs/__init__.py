@@ -14,7 +14,10 @@ and the ``VirtualReplay`` engine (virtual clock):
     works on either clock;
   * ``export``   — Chrome-trace / Perfetto JSON serialization of spans plus
     derived disk/demand-queue occupancy, so a benchmark run renders as an
-    inspectable timeline.
+    inspectable timeline;
+  * ``scopes``   — the fixed names of the served steps' ``jax.named_scope``
+    scopes, which a device trace reads back per op (the served ``Server``
+    counts into a :class:`Registry` and spans the host with the profiler).
 
 Instrumentation cost is itself metered (:class:`Meter`) and charged to the
 prediction ``Overhead`` ledger, so CAPre's zero-overhead claim stays
