@@ -92,18 +92,25 @@ flash_attention_trainable.defvjp(_fat_fwd, _fat_bwd)
 
 @partial(jax.jit, static_argnames=("block_k",))
 def decode_attention(q, k, v, kv_len, *, block_k=512):
-    """q [B, H, D]; k, v [B, S, KV, D]; kv_len scalar -> [B, H, D]."""
+    """q [B, H, D]; k, v [B, S, KV, D]; kv_len scalar -> [B, H, D]: one
+    layer's cache, handed to the kernel as a stack of one."""
+    with scopes.scope("kv_layout"):
+        k, v = (x.transpose(0, 2, 1, 3)[None] for x in (k, v))
+    return decode_attention_stacked(q, k, v, 0, kv_len, block_k=block_k)
+
+
+@partial(jax.jit, static_argnames=("block_k",))
+def decode_attention_stacked(q, k, v, layer, kv_len, *, block_k=512):
+    """q [B, H, D]; k, v the whole cache [L, B, KV, S, D]; layer, kv_len
+    scalars -> [B, H, D], attending to layer ``layer`` in place."""
     B, H, D = q.shape
-    KV = k.shape[2]
+    KV, S = k.shape[2], k.shape[3]
     with scopes.scope("kv_layout"):
         qf = q.reshape(B * KV, H // KV, D)  # heads are kv-major: head h reads kv h // G
-        kf = k.transpose(0, 2, 1, 3).reshape(B * KV, -1, D)
-        vf = v.transpose(0, 2, 1, 3).reshape(B * KV, -1, D)
-    S = kf.shape[1]
     bk = min(block_k, S)
     while S % bk:
         bk //= 2
-    of = decode_attention_kernel(qf, kf, vf, kv_len, block_k=bk, interpret=_interpret())
+    of = decode_attention_kernel(qf, k, v, kv_len, layer, block_k=bk, interpret=_interpret())
     return of.reshape(B, H, D)
 
 

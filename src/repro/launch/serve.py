@@ -21,6 +21,7 @@ from repro.configs import get_config, get_smoke_config
 from repro.core.access_plan import plan_from_jaxpr
 from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.steps import concrete_batch, make_decode_step, make_prefill_step
+from repro.models.transformer import kv_major
 from repro.obs import Registry, scopes
 
 
@@ -57,13 +58,17 @@ def _count_compiles(stats: Registry) -> None:
 
 
 def pad_cache(cfg, cache: dict, max_len: int) -> dict:
-    """Grow seq-dim cache buffers to ``max_len`` (static decode shapes)."""
+    """Grow the seq dim of the k/v cache buffers to ``max_len`` (static
+    decode shapes)."""
     if cfg.family in ("dense", "moe", "encdec"):
-        pad = max_len - cache["k"].shape[2]
+        seq = 3 if kv_major(cfg) else 2  # [L, B, KV, S, hd] or [L, B, S, KV, hd]
+        pad = max_len - cache["k"].shape[seq]
         if pad > 0:
             with scopes.scope("kv_write"):
                 for key in ("k", "v"):
-                    cache[key] = jnp.pad(cache[key], ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
+                    widths = [(0, 0)] * 5
+                    widths[seq] = (0, pad)
+                    cache[key] = jnp.pad(cache[key], widths)
     return cache
 
 
@@ -94,7 +99,9 @@ class Server:
     (every jaxpr trace and backend compile in the process while the Server
     lives, by function name), gauges ``serve.plan_trace_s`` and
     ``serve.plan_walk_s`` (the last ``plan``: tracing the decode step, and
-    walking its jaxpr)."""
+    walking its jaxpr) and ``serve.decode_temp_bytes`` (the last
+    ``compile``: the decode step's scratch memory, which stays far under one
+    layer stack of the cache while the step writes the cache in place)."""
 
     def __init__(self, cfg, mesh=None, max_len: int = 256):
         cfg = cfg.replace(param_dtype=cfg.compute_dtype)
@@ -147,6 +154,9 @@ class Server:
         for name, (fn, a) in args.items():
             t0 = time.perf_counter()
             out[name] = (fn.lower(*a).compile(), time.perf_counter() - t0)
+        memory = out["decode"][0].memory_analysis()
+        if memory is not None:
+            self.stats.gauge("serve.decode_temp_bytes").set(memory.temp_size_in_bytes)
         return out
 
     def generate(self, params, batch: dict, steps: int, greedy: bool = True):

@@ -25,6 +25,7 @@ import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ShapeConfig
+from repro.models.transformer import kv_major
 from .mesh import data_axes
 
 
@@ -152,8 +153,8 @@ def cache_pspecs(cfg: ModelConfig, shape: ShapeConfig, mesh) -> dict:
     cseq = rules["cache_seq"]
     kvh = rules["act_kv"]
     ff = rules["ff"]
+    kv = P(None, b, kvh, cseq, None) if kv_major(cfg) else P(None, b, cseq, kvh, None)
     if cfg.family in ("dense", "moe"):
-        kv = P(None, b, cseq, kvh, None)
         return {"k": kv, "v": kv}
     if cfg.family == "ssm":
         return {
@@ -164,11 +165,10 @@ def cache_pspecs(cfg: ModelConfig, shape: ShapeConfig, mesh) -> dict:
         return {
             "conv": P(None, b, None, ff),
             "rec": P(None, b, ff),
-            "k": P(None, b, cseq, kvh, None),
-            "v": P(None, b, cseq, kvh, None),
+            "k": kv,
+            "v": kv,
         }
     if cfg.family == "encdec":
-        kv = P(None, b, cseq, kvh, None)
         ckv = P(None, b, None, kvh, None)
         return {"k": kv, "v": kv, "cross_k": ckv, "cross_v": ckv}
     raise ValueError(cfg.family)

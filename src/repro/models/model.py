@@ -38,6 +38,7 @@ from .transformer import (
     forward_encoder,
     forward_hybrid,
     forward_stack,
+    kv_major,
 )
 
 # ---------------------------------------------------------------------------
@@ -340,9 +341,12 @@ class Model:
     def _assemble_cache(self, batch, extras):
         cfg = self.cfg
         kvdt = self.kv_dtype()
+        # k/v [L, B, S, KV, hd] as the layer scan collects them, in the
+        # order the decode step reads them
+        stored = lambda kv: (kv.swapaxes(2, 3) if kv_major(cfg) else kv).astype(kvdt)
         if cfg.family in ("dense", "moe"):
             k, v = extras
-            return {"k": k.astype(kvdt), "v": v.astype(kvdt)}
+            return {"k": stored(k), "v": stored(v)}
         if cfg.family == "ssm":
             conv, ssm = extras
             return {"conv": conv, "ssm": ssm}
@@ -357,15 +361,15 @@ class Model:
             if S > W:
                 k = jnp.roll(k[:, :, -W:], shift=S % W, axis=2)
                 v = jnp.roll(v[:, :, -W:], shift=S % W, axis=2)
-            return {"conv": conv, "rec": rec, "k": k.astype(kvdt), "v": v.astype(kvdt)}
+            return {"conv": conv, "rec": rec, "k": stored(k), "v": stored(v)}
         if cfg.family == "encdec":
             dec_extras, _enc_out = extras
             self_kv, cross_kv = dec_extras
             k, v = self_kv
             ck, cv = cross_kv
             return {
-                "k": k.astype(kvdt),
-                "v": v.astype(kvdt),
+                "k": stored(k),
+                "v": stored(v),
                 "cross_k": ck.astype(kvdt),
                 "cross_v": cv.astype(kvdt),
             }
@@ -399,12 +403,17 @@ class Model:
         return jnp.dtype(cfg.kv_cache_dtype or cfg.compute_dtype)
 
     def abstract_cache(self, batch_size: int, seq_len: int) -> dict:
+        """The decode cache.  Self-attention k/v stack the layers' [B, KV,
+        S, hd] where the Pallas kernel reads them (``kv_major``), their [B,
+        S, KV, hd] elsewhere; whisper's cross k/v are always [L, B, F, KV,
+        hd]."""
         cfg = self.cfg
         kvdt = self.kv_dtype()  # k/v caches (may be quantized, e.g. fp8)
         cdt = jnp.dtype(cfg.compute_dtype)  # conv tails / recurrent states
         L, KV, hd = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
+        kv = lambda n, S: (n, batch_size) + ((KV, S) if kv_major(cfg) else (S, KV)) + (hd,)
         if cfg.family in ("dense", "moe"):
-            shp = (L, batch_size, seq_len, KV, hd)
+            shp = kv(L, seq_len)
             return {"k": jax.ShapeDtypeStruct(shp, kvdt), "v": jax.ShapeDtypeStruct(shp, kvdt)}
         if cfg.family == "ssm":
             return {
@@ -424,11 +433,11 @@ class Model:
                     (n_rec, batch_size, cfg.ssm_conv - 1, cfg.lru_width), cdt
                 ),
                 "rec": jax.ShapeDtypeStruct((n_rec, batch_size, cfg.lru_width), jnp.float32),
-                "k": jax.ShapeDtypeStruct((n_attn, batch_size, W, KV, hd), kvdt),
-                "v": jax.ShapeDtypeStruct((n_attn, batch_size, W, KV, hd), kvdt),
+                "k": jax.ShapeDtypeStruct(kv(n_attn, W), kvdt),
+                "v": jax.ShapeDtypeStruct(kv(n_attn, W), kvdt),
             }
         if cfg.family == "encdec":
-            shp = (L, batch_size, seq_len, KV, hd)
+            shp = kv(L, seq_len)
             cshp = (L, batch_size, cfg.enc_positions, KV, hd)
             return {
                 "k": jax.ShapeDtypeStruct(shp, kvdt),

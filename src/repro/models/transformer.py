@@ -229,9 +229,18 @@ def forward_decoder(params, cfg, x, positions, enc_out, mesh_info, *,
 # ---------------------------------------------------------------------------
 
 
-def _decode_attn(x, lp, cfg, dt, k_cache, v_cache, pos, *, window: int = 0):
-    """One-token attention against a cache [B, S, KV, hd]; writes the new
-    k/v at ``pos`` (or ``pos % window`` for ring caches) and attends."""
+def kv_major(cfg) -> bool:
+    """Whether the decode cache's k/v stacks are stored [L, B, KV, S, hd],
+    the order in which the Pallas decode kernel reads a layer's blocks in
+    place; the jnp paths keep the layer scan's [L, B, S, KV, hd]."""
+    return cfg.attn_impl == "pallas"
+
+
+def _decode_attn(x, lp, cfg, dt, k_all, v_all, layer, pos, *, window: int = 0):
+    """One-token attention of layer ``layer`` against the whole cache stack
+    k_all, v_all (ordered as ``kv_major`` says): writes the new k/v in place
+    at ``pos`` (or ``pos % window`` for ring caches) and attends to that
+    layer's slots.  Returns (x, k_all, v_all)."""
     B = x.shape[0]
     with scopes.scope("qkv"):
         h = apply_norm(cfg.norm, x, lp["ln1"], lp.get("ln1_b"))
@@ -243,30 +252,40 @@ def _decode_attn(x, lp, cfg, dt, k_cache, v_cache, pos, *, window: int = 0):
         k = apply_rope(cfg.rope, k, positions, cfg.rope_theta)
     slot = pos % window if window else pos
     with scopes.scope("kv_write"):
-        k_cache = jax.lax.dynamic_update_slice(k_cache, k.astype(k_cache.dtype), (0, slot, 0, 0))
-        v_cache = jax.lax.dynamic_update_slice(v_cache, v.astype(v_cache.dtype), (0, slot, 0, 0))
-    with scopes.scope("attn_core"):
-        if window:
-            # ring buffer: mask by stored-position recency
-            S = k_cache.shape[1]
-            idx = jnp.arange(S)
-            ring_pos = pos - ((slot - idx) % S)  # absolute position stored per slot
-            valid = (ring_pos >= 0) & (ring_pos >= pos - window + 1)
-            o = _masked_decode_attention(q, k_cache, v_cache, valid, cfg)
-        elif cfg.attn_impl == "pallas" and k_cache.shape[1] % 128 == 0:
-            # flash-decode kernel: the KV cache streams HBM->VMEM once (in its
-            # stored dtype — fp8 caches halve the traffic), scores stay in VMEM
-            from repro.kernels.ops import decode_attention as _dec
-
-            o = _dec(q[:, 0], k_cache, v_cache, pos + 1).astype(dt)[:, None]
+        if kv_major(cfg):  # [B, 1, KV, hd] -> [B, KV, 1, hd]
+            k, v, at = k.swapaxes(1, 2), v.swapaxes(1, 2), (layer, 0, 0, slot, 0)
         else:
-            o = gqa_attention(
-                q, k_cache.astype(dt), v_cache.astype(dt), causal=False,
-                impl="naive", q_offset=pos, kv_len=pos + 1,
-            )
+            at = (layer, 0, slot, 0, 0)
+        k_all = jax.lax.dynamic_update_slice(k_all, k.astype(k_all.dtype)[None], at)
+        v_all = jax.lax.dynamic_update_slice(v_all, v.astype(v_all.dtype)[None], at)
+    with scopes.scope("attn_core"):
+        S = k_all.shape[3 if kv_major(cfg) else 2]
+        if kv_major(cfg) and not window and S % 128 == 0:
+            # flash-decode kernel: the layer's KV blocks stream HBM->VMEM
+            # once, straight out of the stack (in its stored dtype — fp8
+            # caches halve the traffic), scores stay in VMEM
+            from repro.kernels.ops import decode_attention_stacked
+
+            o = decode_attention_stacked(q[:, 0], k_all, v_all, layer, pos + 1)
+            o = o.astype(dt)[:, None]
+        else:
+            k_cache, v_cache = k_all[layer], v_all[layer]
+            if kv_major(cfg):
+                k_cache, v_cache = k_cache.swapaxes(1, 2), v_cache.swapaxes(1, 2)
+            if window:
+                # ring buffer: mask by stored-position recency
+                idx = jnp.arange(S)
+                ring_pos = pos - ((slot - idx) % S)  # absolute position stored per slot
+                valid = (ring_pos >= 0) & (ring_pos >= pos - window + 1)
+                o = _masked_decode_attention(q, k_cache, v_cache, valid, cfg)
+            else:
+                o = gqa_attention(
+                    q, k_cache.astype(dt), v_cache.astype(dt), causal=False,
+                    impl="naive", q_offset=pos, kv_len=pos + 1,
+                )
     with scopes.scope("attn_out"):
         x = x + attn_output(o, lp["attn"], cfg, dt)
-    return x, k_cache, v_cache
+    return x, k_all, v_all
 
 
 def _masked_decode_attention(q, k_cache, v_cache, valid, cfg):
@@ -283,18 +302,22 @@ def _masked_decode_attention(q, k_cache, v_cache, valid, cfg):
 
 
 def decode_stack(params, cfg, x, cache, pos, mesh_info):
-    """dense / moe decode: scan over (layer params, cache layers)."""
+    """dense / moe decode: scan over the layer params, carrying the whole
+    cache, which each layer writes in place (no per-layer slice of it goes
+    in or out of the scan, so the step's output aliases the donated input)."""
     dt = cfg_dtype(cfg)
 
-    def body(h, inp):
-        lp, kc, vc = inp
-        h, kc, vc = _decode_attn(h, lp, cfg, dt, kc, vc, pos)
+    def body(carry, inp):
+        h, k_all, v_all = carry
+        lp, layer = inp
+        h, k_all, v_all = _decode_attn(h, lp, cfg, dt, k_all, v_all, layer, pos)
         h = ffn_block(h, lp, cfg, dt, mesh_info)
-        return h, (kc, vc)
+        return (h, k_all, v_all), None
 
+    layers = jnp.arange(cache["k"].shape[0], dtype=jnp.int32)
     with scopes.scope("layers"):
-        x, (k_new, v_new) = jax.lax.scan(body, x, (params["layers"], cache["k"], cache["v"]))
-    return x, {"k": k_new, "v": v_new}
+        (x, k, v), _ = jax.lax.scan(body, (x, cache["k"], cache["v"]), (params["layers"], layers))
+    return x, {"k": k, "v": v}
 
 
 def decode_ssm(params, cfg, x, cache, mesh_info):
@@ -317,7 +340,8 @@ def decode_ssm(params, cfg, x, cache, mesh_info):
 def decode_hybrid(params, cfg, x, cache, pos, mesh_info):
     dt = cfg_dtype(cfg)
     rec_i = attn_i = 0
-    new_conv, new_rec, new_k, new_v = [], [], [], []
+    new_conv, new_rec = [], []
+    k_all, v_all = cache["k"], cache["v"]
     for li in range(cfg.n_layers):
         kind = cfg.block_pattern[li % len(cfg.block_pattern)]
         if kind == "rec":
@@ -336,30 +360,29 @@ def decode_hybrid(params, cfg, x, cache, pos, mesh_info):
             rec_i += 1
         else:
             lp = jax.tree.map(lambda a: a[attn_i], params["attn_layers"])
-            x, kc, vc = _decode_attn(
-                x, lp, cfg, dt, cache["k"][attn_i], cache["v"][attn_i], pos,
-                window=cfg.local_window,
+            x, k_all, v_all = _decode_attn(
+                x, lp, cfg, dt, k_all, v_all, attn_i, pos, window=cfg.local_window,
             )
             x = ffn_block(x, lp, cfg, dt, None)
-            new_k.append(kc)
-            new_v.append(vc)
             attn_i += 1
     return x, {
         "conv": jnp.stack(new_conv),
         "rec": jnp.stack(new_rec),
-        "k": jnp.stack(new_k),
-        "v": jnp.stack(new_v),
+        "k": k_all,
+        "v": v_all,
     }
 
 
 def decode_encdec(params, cfg, x, cache, pos, mesh_info):
-    """whisper decode: self-attn against the self cache + cross-attn against
-    the prefilled cross kv."""
+    """whisper decode: self-attn against the self cache (carried and written
+    in place, as in ``decode_stack``) + cross-attn against the prefilled
+    cross kv."""
     dt = cfg_dtype(cfg)
 
-    def body(h, inp):
-        lp, kc, vc, ck, cv = inp
-        h, kc, vc = _decode_attn(h, lp, cfg, dt, kc, vc, pos)
+    def body(carry, inp):
+        h, k_all, v_all = carry
+        lp, layer, ck, cv = inp
+        h, k_all, v_all = _decode_attn(h, lp, cfg, dt, k_all, v_all, layer, pos)
         hc = apply_norm(cfg.norm, h, lp["lnc"], lp.get("lnc_b"))
         cast = lambda w: w.astype(dt)
         cp = lp["cross"]
@@ -368,13 +391,14 @@ def decode_encdec(params, cfg, x, cache, pos, mesh_info):
         oc = gqa_attention(qc, ck.astype(dt), cv.astype(dt), causal=False, impl="naive")
         h = h + oc.reshape(B, 1, cfg.q_dim) @ cast(cp["wo"])
         h = ffn_block(h, lp, cfg, dt, mesh_info)
-        return h, (kc, vc)
+        return (h, k_all, v_all), None
 
-    x, (k_new, v_new) = jax.lax.scan(
-        body, x,
-        (params["dec_layers"], cache["k"], cache["v"], cache["cross_k"], cache["cross_v"]),
+    layers = jnp.arange(cache["k"].shape[0], dtype=jnp.int32)
+    (x, k, v), _ = jax.lax.scan(
+        body, (x, cache["k"], cache["v"]),
+        (params["dec_layers"], layers, cache["cross_k"], cache["cross_v"]),
     )
-    return x, {"k": k_new, "v": v_new, "cross_k": cache["cross_k"], "cross_v": cache["cross_v"]}
+    return x, {"k": k, "v": v, "cross_k": cache["cross_k"], "cross_v": cache["cross_v"]}
 
 
 def cfg_dtype(cfg):

@@ -30,6 +30,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.models.transformer import kv_major
+
 
 @dataclass
 class Request:
@@ -141,6 +143,8 @@ class ContinuousBatcher:
             S = len(req.prompt)
             batch = {"inputs": jnp.asarray(req.prompt, jnp.int32)[None]}
             logits, cache1 = self._prefill(self.params, batch)
+            if kv_major(self.model.cfg):  # the batcher's own decode reads [L, B, S, KV, hd]
+                cache1 = {key: x.swapaxes(2, 3) for key, x in cache1.items()}
             # hand the prefilled rows to the slot's cache segment
             pad = self.max_len - cache1["k"].shape[2]
             for key in ("k", "v"):

@@ -97,6 +97,22 @@ def test_decode_attention_matches_ref(B, S, H, KV, D, kv_len, dtype):
     )
 
 
+@pytest.mark.parametrize("layer", [0, 2, 4])  # first, middle, last of 5
+@pytest.mark.parametrize("H,KV", [(4, 4), (32, 2)])  # MHA (G = 1), GQA (G = 16)
+@pytest.mark.parametrize("kv_len", [300, 129])  # not a multiple of the 128 block
+def test_decode_attention_stacked_matches_ref(layer, H, KV, kv_len):
+    """The kernel attends to one layer of the whole stacked cache
+    [L, B, KV, S, D], read in place, as the oracle does to that layer."""
+    L, B, S, D = 5, 2, 512, 64
+    rng = np.random.RandomState(8)
+    q = jnp.asarray(rng.randn(B, H, D), jnp.float32)
+    k = jnp.asarray(rng.randn(L, B, KV, S, D), jnp.float32)
+    v = jnp.asarray(rng.randn(L, B, KV, S, D), jnp.float32)
+    got = ops.decode_attention_stacked(q, k, v, layer, kv_len, block_k=128)
+    want = ref.decode_attention_ref(q, k[layer].swapaxes(1, 2), v[layer].swapaxes(1, 2), kv_len)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), **_tol(jnp.float32))
+
+
 # ---------------------------------------------------------------------------
 # prefetch gather
 # ---------------------------------------------------------------------------

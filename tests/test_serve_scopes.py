@@ -6,7 +6,7 @@ two halves."""
 import contextlib
 import re
 import weakref
-from collections import defaultdict
+from collections import Counter, defaultdict
 
 import jax
 import jax.numpy as jnp
@@ -71,23 +71,44 @@ def _ops_by_scope(hlo: str) -> dict:
     return out
 
 
+def _shapes_by_scope(hlo: str, opcode: str) -> dict:
+    """{scope: [dims of each ``opcode`` op's result, 1s dropped]}, each op
+    under the innermost scope on its op_name path."""
+    out = defaultdict(list)
+    for dims, name in re.findall(rf"= \w+\[([\d,]*)\]\S* {opcode}\(.*op_name=\"([^\"]*)\"", hlo):
+        inner = [p for p in name.split("/") if p in scopes.SCOPES]
+        out[inner[-1] if inner else ""].append(tuple(int(d) for d in dims.split(",") if d != "1"))
+    return out
+
+
 def test_scopes_name_the_ops_they_cover():
-    lowered = _lowered(_server("pallas"))
+    server = _server("pallas")
+    lowered = _lowered(server)
     # before optimisation (a nested jit's ops carry their path from its own
     # root) and as compiled (inlined, with whole paths; the CPU compiler
     # folds the layout transposes away)
-    ops = {step: _ops_by_scope(low.as_text(dialect="hlo", debug_info=True))
-           for step, low in lowered.items()}
-    compiled = {step: _ops_by_scope(low.compile().as_text()) for step, low in lowered.items()}
+    hlo = {step: low.as_text(dialect="hlo", debug_info=True) for step, low in lowered.items()}
+    ops = {step: _ops_by_scope(text) for step, text in hlo.items()}
+    texts = {step: low.compile().as_text() for step, low in lowered.items()}
+    compiled = {step: _ops_by_scope(text) for step, text in texts.items()}
     for step, names in READ.items():
         assert set(names) <= set(ops[step]) | set(compiled[step]), (step, sorted(ops[step]))
-    # the cache written at the new position, and laid out for the kernel
+    # the new position written into the stacked cache, which the kernel
+    # reads as it is stored: no transpose of the cache
+    cache = server.model.abstract_cache(B, server.max_len)["k"].shape
+    # an op's result holds a layer of the cache (in any order) or more
+    holds_cache = lambda dims: Counter(d for d in cache[1:] if d != 1) <= Counter(dims)
     assert ops["decode"]["kv_write"]["dynamic-update-slice"] == 2
-    assert ops["decode"]["kv_layout"]["transpose"] == 2
+    assert ops["decode"]["kv_layout"]["transpose"] == 0
+    assert not any(holds_cache(d) for ds in _shapes_by_scope(hlo["decode"], "transpose").values()
+                   for d in ds)
     assert ops["prefill"]["kv_layout"]["transpose"] == 4  # q, k, v folded for flash_fwd, o unfolded
     assert compiled["prefill"]["kv_write"]["pad"] == 2  # the cache grown to its length
-    # the layer scan slices each layer's weights and cache out of the stacks
+    # the layer scan slices each layer's weights out of their stacks, and
+    # nothing out of the cache, which it carries whole
     assert compiled["decode"]["layers"]["dynamic-slice"] > 0
+    sliced = _shapes_by_scope(texts["decode"], "dynamic-slice")["layers"]
+    assert sliced and not any(holds_cache(d) for d in sliced)
     assert ops["decode"]["attn_out"]["add"] == 1  # the residual add
     assert compiled["decode"]["embed"]["gather"] == 1
     with pytest.raises(ValueError):
@@ -139,6 +160,9 @@ def test_steps_still_lower_and_compile(served):
     server, params = served
     out = server.compile(params, {"inputs": jnp.zeros((B, S), jnp.int32)})
     assert set(out) == {"prefill", "decode"}
+    temp = out["decode"][0].memory_analysis().temp_size_in_bytes
+    assert "serve.decode_temp_bytes" in server.stats.snapshot()["gauges"]
+    assert _value(server.stats, "gauges", "serve.decode_temp_bytes") == temp
     assert "kv_write" in server.prefill.lower(params, {"inputs": jnp.zeros((B, S), jnp.int32)}
                                               ).as_text(debug_info=True)
 

@@ -1,6 +1,7 @@
 """Compile for a described TPU v5e, without a chip: the Pallas kernels at
 qwen1.5-4b widths with ``interpret=False``, and the served full-width bf16
-prefill and decode steps with their memory analysis.
+prefill and decode steps with their memory analysis, including that the
+decode step writes its cache in place.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and it keeps it until
@@ -56,8 +57,10 @@ def test_decode_attention_compiles(one_chip):
 
     s = lambda shape, dt=jnp.bfloat16: _spec(one_chip, shape, dt)
     c = _compile(
-        lambda q, k, v, n: decode_attention_kernel(q, k, v, n, block_k=128, interpret=False),
-        s((B * KV, H // KV, D)), s((B * KV, SC, D)), s((B * KV, SC, D)), s((), jnp.int32),
+        lambda q, k, v, n, layer: decode_attention_kernel(q, k, v, n, layer, block_k=128,
+                                                          interpret=False),
+        s((B * KV, H // KV, D)), s((2, B, KV, SC, D)), s((2, B, KV, SC, D)), s((), jnp.int32),
+        s((), jnp.int32),
     )
     assert "tpu_custom_call" in c.as_text()
 
@@ -164,3 +167,33 @@ def test_full_width_prefill_step_fits_one_chip(served, one_chip, compiled_kernel
     c = server.prefill.lower(params, {"inputs": _spec(one_chip, (B, SQ), jnp.int32)}).compile()
     assert "tpu_custom_call" in c.as_text()
     _fits(c)
+
+
+@pytest.mark.parametrize("arch", ["qwen1_5_4b", "chatglm3_6b"])
+def test_decode_step_writes_cache_in_place(served, one_chip, compiled_kernels, arch):
+    """The decode step carries the stacked cache through its layer scan and
+    writes the new position in place: no copy of a whole stack and no
+    buffer of its shape, scratch under one K stack, and both stacks aliased
+    to the donated input.  qwen1.5-4b (MHA) at full width; chatglm3-6b's
+    widths (GQA, 2 KV heads) at 4 layers."""
+    from repro.configs import get_config
+    from repro.launch.serve import Server
+
+    server, params, cache = served
+    if arch != "qwen1_5_4b":
+        cfg = get_config(arch).replace(attn_impl="pallas", n_layers=4)
+        server = Server(cfg, max_len=SQ + 32)
+        place = lambda tree: jax.tree.map(lambda x: _spec(one_chip, x.shape, x.dtype), tree)
+        params = place(server.model.abstract_params())
+        cache = place(server.model.abstract_cache(B, server.max_len))
+    c = server.decode.lower(
+        params, cache, _spec(one_chip, (B, 1), jnp.int32), _spec(one_chip, (), jnp.int32)
+    ).compile()
+    stack = "[" + ",".join(map(str, cache["k"].shape)) + "]"
+    for line in c.as_text().splitlines():
+        if stack in line.split(" = ", 1)[-1].split(" ", 1)[0]:  # the op's result type
+            assert " copy(" not in line and "AllocateBuffer" not in line, line
+    m = c.memory_analysis()
+    k_bytes = cache["k"].size * cache["k"].dtype.itemsize
+    assert m.temp_size_in_bytes < k_bytes
+    assert m.alias_size_in_bytes >= 2 * k_bytes
