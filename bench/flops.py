@@ -1,15 +1,20 @@
-"""Operations and bytes that the work of a dense decoder needs, from its
-shapes alone (the least the chip must do, not what the program does).
+"""Operations and bytes that the work of a decoder needs, from its shapes
+alone (the least the chip must do, not what the program does).
 
-``m`` is a configuration's ``model`` block.  Weights are held in
-``m["dtype"]`` (2 bytes for bfloat16) and so is the KV cache.  Matmul
-FLOPs count 2 per multiply-add; causal attention counts only the keys at
-or before each query.
+``m`` is a configuration's ``model`` block with its ``reference`` name
+(``Cell.model``).  A layer's matmul parameters and the weight bytes a
+decode step reads are its family's (``bench/reference/<family>.py``);
+attention, the KV cache and the head's FLOPs are counted here.  Weights
+are held in ``m["dtype"]`` (2 bytes for bfloat16) and so is the KV cache.
+Matmul FLOPs count 2 per multiply-add; causal attention counts only the
+keys at or before each query.
 """
 
 from __future__ import annotations
 
 import jax.numpy as jnp
+
+from bench.reference import family
 
 
 def _width(m: dict) -> int:
@@ -17,16 +22,13 @@ def _width(m: dict) -> int:
 
 
 def layer_matmul_params(m: dict) -> int:
-    d, f = m["d_model"], m["d_ff"]
-    q, kv = m["n_heads"] * m["head_dim"], m["n_kv_heads"] * m["head_dim"]
-    return d * q + 2 * d * kv + q * d + 3 * d * f
+    """Matmul parameters one token passes through in one layer."""
+    return family(m).layer_matmul_params(m)
 
 
 def layer_params(m: dict) -> int:
-    """Every parameter of one layer: matmuls, biases, two norms."""
-    q, kv = m["n_heads"] * m["head_dim"], m["n_kv_heads"] * m["head_dim"]
-    bias = q + 2 * kv if m["qkv_bias"] else 0
-    return layer_matmul_params(m) + bias + 2 * m["d_model"]
+    """Every parameter of one layer."""
+    return family(m).layer_params(m)
 
 
 def kv_bytes_per_token(m: dict) -> int:
@@ -57,12 +59,10 @@ def decode_flops(m: dict, batch: int, kv_len: float) -> float:
 
 
 def decode_min_bytes(m: dict, batch: int, kv_len: float) -> float:
-    """HBM bytes one decode step must move at least: every layer weight and
-    the head once, the batch's embedding rows, K/V read up to ``kv_len``
-    (the new position included) and the new K/V written."""
-    w = _width(m)
-    weights = (m["n_layers"] * layer_params(m) + m["d_model"] * m["vocab_size"]
-               + m["d_model"] + batch * m["d_model"]) * w
+    """HBM bytes one decode step must move at least: the weights its family
+    says a step of ``batch`` tokens reads, K/V read up to ``kv_len`` (the new
+    position included) and the new K/V written."""
+    weights = family(m).decode_weight_bytes(m, batch)
     return weights + kv_bytes_per_token(m) * batch * kv_len
 
 

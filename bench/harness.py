@@ -11,14 +11,16 @@ one before reaches the host.  After the window the served state is freed and
 the reference (``bench/reference/<family>.py``) recomputes a seeded sample of
 the finished requests.
 
-Nothing here is specific to one cell: a cell is its configuration, traffic
-and cell files, and a metric is its reader under ``bench/metrics/``.
+Nothing here is specific to one cell or one model family: a cell is its
+configuration, traffic and cell files, a family is its module under
+``bench/reference/``, and a metric is its reader under ``bench/metrics/``.
+The Pallas kernels told apart in a trace are those the cell's readers
+declare (``KERNEL``).
 """
 
 from __future__ import annotations
 
 import gc
-import importlib
 import importlib.util
 import json
 import os
@@ -32,11 +34,11 @@ from pathlib import Path
 import numpy as np
 
 from bench import traffic
+from bench.reference import family
 
 ROOT = Path(__file__).resolve().parents[1]
 BENCH = ROOT / "bench"
 TRACE_SECONDS = 3.0  # the traced part of a --trace 1 window
-KERNELS = ("decode_attention", "flash_fwd")  # Pallas kernels named in traces
 
 
 class NoDevice(Exception):
@@ -59,10 +61,18 @@ class Cell:
     check: dict  # the cell file: sample size and limits
     end_to_end: list  # the metric entries this cell reports, by mode
     per_layer: list
+    root: Path = ROOT  # the benchmark's root: its files and metric readers
 
     @property
     def model(self) -> dict:
-        return self.config["model"]
+        return model_block(self.config)
+
+
+def model_block(config: dict) -> dict:
+    """A configuration's ``model`` block with its family's name
+    (``reference``), as the family's module, ``weights`` and ``flops`` take
+    it."""
+    return {**config["model"], "reference": config["reference"]}
 
 
 def _reports(metric: dict, workload: str) -> bool:
@@ -85,7 +95,7 @@ def load_cell(workload: str, root: Path = ROOT) -> Cell:
     reported = {m["name"] for m in e2e}
     layer = [m for m in manifest["per_layer"]
              if _reports(m, workload) and m["moves"] in reported]
-    return Cell(workload, int(w["chips"]), config, mix, check, e2e, layer)
+    return Cell(workload, int(w["chips"]), config, mix, check, e2e, layer, root)
 
 
 def check_published(config: dict) -> None:
@@ -99,21 +109,13 @@ def check_published(config: dict) -> None:
 
 def program_config(config: dict):
     """The program's own configuration of this model, checked against the
-    file: every width, the rotary kind, biases and the served dtype."""
+    file: each field that the family's ``program_fields`` names."""
     from repro.configs import get_config, get_smoke_config
 
     name = config["program_config"]
     cfg = (get_smoke_config if config.get("program_preset") == "smoke" else get_config)(name)
-    m = config["model"]
-    want = {
-        "family": "dense", "norm": "rmsnorm", "mlp": "swiglu",
-        "n_layers": m["n_layers"], "d_model": m["d_model"], "n_heads": m["n_heads"],
-        "n_kv_heads": m["n_kv_heads"], "head_dim": m["head_dim"], "d_ff": m["d_ff"],
-        "vocab_size": m["vocab_size"], "rope": m["rope"], "rope_theta": m["rope_theta"],
-        "qkv_bias": m["qkv_bias"], "tie_embeddings": m["tie_embeddings"],
-        "compute_dtype": m["dtype"], "attn_out_bias": False, "mlp_bias": False,
-        "qk_norm": False,
-    }
+    m = model_block(config)
+    want = family(m).program_fields(m)
     bad = {k: (getattr(cfg, k), v) for k, v in want.items() if getattr(cfg, k) != v}
     if bad:
         raise ValueError(f"repro.configs {name} differs from the file: {bad}")
@@ -417,8 +419,8 @@ def gaps(cell: Cell, key, vocab_rows: int, seed: int, window: Window, picks: lis
     instead of the served token (``served_gap`` keeps the program's)."""
     import jax.numpy as jnp
 
-    ref = importlib.import_module(f"bench.reference.{cell.config['reference']}")
     mix, m = cell.mix, cell.model
+    ref = family(m)
     P, O = mix.prompt_len, mix.output_len
     by_index = {b.index: b for b in window.batches}
     rows, served = [], []
@@ -460,12 +462,32 @@ class Run:
     trace: object = None
 
 
-def read_metric(name: str, run: Run):
-    path = BENCH / "metrics" / f"{name}.py"
+def reader(name: str, root: Path = ROOT):
+    """The metric's reader, ``bench/metrics/<name>.py`` under the root."""
+    path = root / "bench" / "metrics" / f"{name}.py"
     spec = importlib.util.spec_from_file_location(f"bench_metric_{name.replace('.', '_')}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read(run)
+    return mod
+
+
+def read_metric(name: str, run: Run):
+    return reader(name, run.cell.root).read(run)
+
+
+def kernels(cell: Cell) -> tuple:
+    """The Pallas kernels to tell apart in the cell's trace: the ``KERNEL``
+    that each of its per-layer readers declares."""
+    declared = (getattr(reader(m["name"], cell.root), "KERNEL", None) for m in cell.per_layer)
+    return tuple(sorted({k for k in declared if k}))
+
+
+def load_trace(xplane: Path, cell: Cell):
+    """The cell's trace with each op's scope (``scope_trace.load``), its
+    readers' kernels told apart."""
+    from bench import scope_trace
+
+    return scope_trace.load(xplane, kernels(cell))
 
 
 def run_cell(workload: str, seed: int, seconds: float, trace: bool, t_start: float,
@@ -506,10 +528,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, t_start: flo
     served.free()
     del served
     if tmp is not None:
-        from bench import trace_reduce as tr
-
-        xplane = next(Path(tmp).rglob("*.xplane.pb"))
-        window.trace = tr.load(xplane, KERNELS)
+        window.trace = load_trace(next(Path(tmp).rglob("*.xplane.pb")), cell)
         _rmtree(tmp)
     picks = sample(window, cell, seed)
     t0 = time.perf_counter()

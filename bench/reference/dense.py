@@ -4,11 +4,12 @@ no cache, no batching of its own beyond rows of equal length.
 It follows the published layer equations: pre-norm RMSNorm, attention with
 QKV bias and grouped KV heads (query head h reads KV head h // G), rotary
 embedding on the whole head (``default``) or its first half (``half``,
-ChatGLM), SwiGLU feed-forward, a final RMSNorm and an untied output head.
-Rotary pairs dimension i with i + n/2 of the rotated width (the rotate-half
-form); ChatGLM's own code pairs neighbours (2i, 2i+1), which is the same
-model up to a fixed permutation of the q and k columns, so under weights
-drawn at random it is the same model.
+ChatGLM), SwiGLU feed-forward, a final RMSNorm and the output head (the
+embedding's transpose where the configuration ties them).  Rotary pairs
+dimension i with i + n/2 of the rotated width (the rotate-half form);
+ChatGLM's own code pairs neighbours (2i, 2i+1), which is the same model up
+to a fixed permutation of the q and k columns, so under weights drawn at
+random it is the same model.
 
 It imports nothing of the program.  Weights come from ``bench.weights``,
 drawn from the seed again one layer at a time, upcast to float32, so the
@@ -18,6 +19,9 @@ whole model is never held at once.  Matmuls run at ``highest`` precision
 ``quant="fp8"`` is the control: every linear layer's weights and inputs
 rounded to float8 e4m3 (per output column and per row), the precision a
 later change might serve in.
+
+The module is also the family's home in the benchmark (``bench.reference``):
+its leaves, the program fields it must match, and its least work.
 """
 
 from __future__ import annotations
@@ -30,6 +34,96 @@ import jax.numpy as jnp
 from bench import weights as W
 
 F8_MAX = 448.0  # largest finite float8_e4m3fn
+
+# -- the family's leaves -----------------------------------------------------
+
+# (name, init, scale) of each leaf, as bench.weights draws them; a leaf's
+# place in its table is folded into its key, so the order is fixed.
+LAYER = (
+    ("ln1", "around_one", 0.1),
+    ("ln2", "around_one", 0.1),
+    ("attn.wq", "normal", None),
+    ("attn.wk", "normal", None),
+    ("attn.wv", "normal", None),
+    ("attn.wo", "normal", None),
+    ("attn.bq", "normal", 0.1),
+    ("attn.bk", "normal", 0.1),
+    ("attn.bv", "normal", 0.1),
+    ("mlp.wi_gate", "normal", None),
+    ("mlp.wi_up", "normal", None),
+    ("mlp.wo", "normal", None),
+)
+TOP = (
+    ("embed", "normal", 1.0),
+    ("lm_head", "normal", None),
+    ("final_norm", "around_one", 0.1),
+)
+
+
+def layer_shapes(m: dict) -> dict:
+    d, f = m["d_model"], m["d_ff"]
+    q, kv = m["n_heads"] * m["head_dim"], m["n_kv_heads"] * m["head_dim"]
+    shapes = {
+        "ln1": (d,), "ln2": (d,),
+        "attn.wq": (d, q), "attn.wk": (d, kv), "attn.wv": (d, kv), "attn.wo": (q, d),
+        "mlp.wi_gate": (d, f), "mlp.wi_up": (d, f), "mlp.wo": (f, d),
+    }
+    if m["qkv_bias"]:
+        shapes.update({"attn.bq": (q,), "attn.bk": (kv,), "attn.bv": (kv,)})
+    return shapes
+
+
+def top_shapes(m: dict, vocab_rows: int) -> dict:
+    """``vocab_rows``: the embedding rows the program holds (its vocab
+    padded); rows past ``vocab_size`` are drawn but never read."""
+    d = m["d_model"]
+    shapes = {"embed": (vocab_rows, d), "final_norm": (d,)}
+    if not m["tie_embeddings"]:
+        shapes["lm_head"] = (d, vocab_rows)
+    return shapes
+
+
+def program_fields(m: dict) -> dict:
+    """The program's ``ModelConfig`` fields that must equal the file's:
+    every width, the rotary kind, biases and the served dtype."""
+    return {
+        "family": "dense", "norm": "rmsnorm", "mlp": "swiglu",
+        "n_layers": m["n_layers"], "d_model": m["d_model"], "n_heads": m["n_heads"],
+        "n_kv_heads": m["n_kv_heads"], "head_dim": m["head_dim"], "d_ff": m["d_ff"],
+        "vocab_size": m["vocab_size"], "rope": m["rope"], "rope_theta": m["rope_theta"],
+        "qkv_bias": m["qkv_bias"], "tie_embeddings": m["tie_embeddings"],
+        "compute_dtype": m["dtype"], "attn_out_bias": False, "mlp_bias": False,
+        "qk_norm": False,
+    }
+
+
+# -- the family's least work (bench.flops reads it) --------------------------
+
+
+def layer_matmul_params(m: dict) -> int:
+    """Matmul parameters one token passes through in one layer (its matmul
+    FLOPs are twice this)."""
+    d, f = m["d_model"], m["d_ff"]
+    q, kv = m["n_heads"] * m["head_dim"], m["n_kv_heads"] * m["head_dim"]
+    return d * q + 2 * d * kv + q * d + 3 * d * f
+
+
+def layer_params(m: dict) -> int:
+    """Every parameter of one layer: matmuls, biases, two norms."""
+    q, kv = m["n_heads"] * m["head_dim"], m["n_kv_heads"] * m["head_dim"]
+    bias = q + 2 * kv if m["qkv_bias"] else 0
+    return layer_matmul_params(m) + bias + 2 * m["d_model"]
+
+
+def decode_weight_bytes(m: dict, batch: int) -> int:
+    """Weight bytes a decode step of ``batch`` tokens must read: every layer
+    weight and the head once, the final norm, the batch's embedding rows."""
+    d = m["d_model"]
+    params = m["n_layers"] * layer_params(m) + d * m["vocab_size"] + d + batch * d
+    return params * jnp.dtype(m["dtype"]).itemsize
+
+
+# -- the reference --------------------------------------------------------------
 
 
 def _round_fp8(x, axis):
@@ -105,7 +199,8 @@ def _head(mf, quant, V, x, lt):
     m = dict(mf)
     with jax.default_matmul_precision("highest"):
         h = _rmsnorm(x, lt["final_norm"], m["norm_eps"])
-        return _linear(h, lt["lm_head"][:, :V], quant)
+        head = lt["lm_head"] if "lm_head" in lt else lt["embed"].T
+        return _linear(h, head[:, :V], quant)
 
 
 def logits(m: dict, seed_key, vocab_rows: int, tokens, first: int, quant=None, block=1):

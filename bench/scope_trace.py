@@ -12,8 +12,12 @@ scope named on the op's ``tf_op`` path, ``""`` where none is) and
 without scopes reads ``""`` for every op, and a trace kept as JSON without
 the two fields loads and reads as before.  The script prints, for each
 served program, its device time per scope and what no scope covers, the
-per-step times of ``STEP_MS``, and the longest idle gaps by the span over
-them.
+per-step times that the metric readers read (:func:`step_metrics`), and the
+longest idle gaps by the span over them.
+
+A reader of the program's scopes declares ``PROGRAM`` and ``SCOPES`` and
+returns ``scope_ms(run.trace, PROGRAM, SCOPES)``; a new scope needs nothing
+here.
 """
 
 from __future__ import annotations
@@ -25,8 +29,8 @@ from collections import defaultdict
 from dataclasses import dataclass, field
 from pathlib import Path
 
+ROOT = Path(__file__).resolve().parents[1]
 if __name__ == "__main__":
-    ROOT = Path(__file__).resolve().parents[1]
     sys.path[:] = [str(ROOT), str(ROOT / "src")] + [
         p for p in sys.path if Path(p or ".").resolve() != ROOT / "bench"]
 
@@ -35,14 +39,6 @@ from bench import trace_reduce as tr  # noqa: E402
 PROGRAM_PREFIX = "serve."
 TF_OP = "tf_op"  # the stat of an op's metadata that holds its HLO op_name
 PROGRAMS = ("jit_decode_step", "jit_prefill")
-
-# Per-step device times of layers, in ms: the program, and the scopes summed
-# over each of its runs.
-STEP_MS = {
-    "decode_kv_ms": ("jit_decode_step", ("kv_write", "kv_layout", "layers")),
-    "decode_linear_ms": ("jit_decode_step", ("embed", "qkv", "attn_out", "ffn", "logits")),
-    "prefill_attn_ms": ("jit_prefill", ("attn_core", "kv_layout")),
-}
 
 
 def scope_names() -> tuple:
@@ -130,14 +126,30 @@ class ScopedTrace(tr.Trace):
                    d.get("scopes", {}), d.get("program", []))
 
 
-def step_ms(trace: ScopedTrace, name: str):
-    """One of ``STEP_MS`` per run of its program, or None where the trace
-    holds no run of it or no op of it under any scope."""
-    program, parts = STEP_MS[name]
+def scope_ms(trace: ScopedTrace, program: str, scopes) -> float | None:
+    """Device ms per run of ``program`` in the ops under ``scopes``, or None
+    where the trace holds no run of it or no op of it under any scope (a
+    program without scopes)."""
     runs, ns = trace.scope_ns(program)
     if not runs or not any(v for s, v in ns.items() if s):
         return None
-    return sum(ns.get(s, 0.0) for s in parts) / runs * 1e-6
+    return sum(ns.get(s, 0.0) for s in scopes) / runs * 1e-6
+
+
+def readers(root: Path = ROOT) -> dict:
+    """Every metric reader under ``root``, by metric name."""
+    from bench.harness import reader
+
+    return {p.stem: reader(p.stem, root)
+            for p in sorted((root / "bench" / "metrics").glob("*.py"))
+            if not p.stem.startswith("_")}
+
+
+def step_metrics(root: Path = ROOT) -> dict:
+    """{metric: (program, scopes)} of the readers that read the program's
+    scopes (those that declare ``SCOPES``)."""
+    return {name: (mod.PROGRAM, mod.SCOPES) for name, mod in readers(root).items()
+            if hasattr(mod, "SCOPES")}
 
 
 def _messages():
@@ -250,7 +262,7 @@ def report(t: ScopedTrace) -> dict:
                                    if v > 0.01 * total},
             "ops_over_module_pct": 100 * total / module_ns,
         }
-    out["step_ms"] = {name: step_ms(t, name) for name in STEP_MS}
+    out["step_ms"] = {name: scope_ms(t, *spec) for name, spec in step_metrics().items()}
     out["gaps"] = t.program_gaps(10)
     return out
 
@@ -262,7 +274,8 @@ if __name__ == "__main__":
     ap.add_argument("xplane", type=Path)
     ap.add_argument("--json", type=Path, help="also keep the scoped trace here")
     args = ap.parse_args()
-    trace = load(args.xplane, ("decode_attention", "flash_fwd"))
+    declared = {getattr(mod, "KERNEL", None) for mod in readers().values()}
+    trace = load(args.xplane, tuple(sorted(declared - {None})))
     if args.json:
         args.json.write_text(trace.to_json())
     print(json.dumps(report(trace), indent=1))

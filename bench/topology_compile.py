@@ -38,7 +38,7 @@ def main(workloads) -> int:
     from jax.sharding import SingleDeviceSharding
 
     from bench import harness, weights
-    from bench.reference import dense
+    from bench.reference import family
     from repro.kernels import ops
     from repro.launch.serve import Server
 
@@ -69,7 +69,7 @@ def main(workloads) -> int:
         n = int(cell.check["sample_requests"])
         lw = place(jax.eval_shape(lambda k: weights._layer_body(
             weights._freeze(m), k, jnp.uint32(0), jnp.dtype(m["dtype"])), spec((2,), jnp.uint32)))
-        out["reference layer"] = dense._layer.lower(
+        out["reference layer"] = family(m)._layer.lower(
             weights._freeze(m), None, block, spec((n, T, m["d_model"]), jnp.float32), lw).compile()
         for step, c in out.items():
             kernels = "tpu_custom_call" in c.as_text()

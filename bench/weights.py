@@ -1,4 +1,4 @@
-"""Weights of a dense decoder, drawn on the device from the run's seed.
+"""Weights of a decoder, drawn on the device from the run's seed.
 
 ``make_params`` builds the whole served tree in one jitted program, in the
 served dtype and in the program's layout (stacked layers).  ``layer`` and
@@ -7,9 +7,13 @@ reference can rebuild them after the served copy is freed.  Every value is
 a function of (seed, leaf, layer) alone: the same seed gives the same
 weights in either form.
 
-Scales keep a 40-layer random stack well conditioned: unit-variance
-embeddings, 1/sqrt(fan_in) projections, norms and biases drawn away from
-their trivial values (1 and 0) so that a wrong or missing one shows.
+The leaves, their shapes and scales are the family's (``LAYER``, ``TOP``,
+``layer_shapes``, ``top_shapes`` of ``bench/reference/<family>.py``, found
+by the ``model`` block's ``reference``).  A leaf's key folds in its index in
+the family's table.  Scales keep a 40-layer random stack well conditioned:
+unit-variance embeddings, 1/sqrt(fan_in) projections, norms and biases drawn
+away from their trivial values (1 and 0) so that a wrong or missing one
+shows.
 """
 
 from __future__ import annotations
@@ -17,53 +21,17 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-# (name, init, scale): "normal" draws scale * N(0, 1); "around_one" draws
-# 1 + scale * N(0, 1).  A scale of None means 1/sqrt(fan_in).
-_LAYER = (
-    ("ln1", "around_one", 0.1),
-    ("ln2", "around_one", 0.1),
-    ("attn.wq", "normal", None),
-    ("attn.wk", "normal", None),
-    ("attn.wv", "normal", None),
-    ("attn.wo", "normal", None),
-    ("attn.bq", "normal", 0.1),
-    ("attn.bk", "normal", 0.1),
-    ("attn.bv", "normal", 0.1),
-    ("mlp.wi_gate", "normal", None),
-    ("mlp.wi_up", "normal", None),
-    ("mlp.wo", "normal", None),
-)
-_TOP = (
-    ("embed", "normal", 1.0),
-    ("lm_head", "normal", None),
-    ("final_norm", "around_one", 0.1),
-)
-
-
-def layer_shapes(m: dict) -> dict:
-    d, f = m["d_model"], m["d_ff"]
-    q, kv = m["n_heads"] * m["head_dim"], m["n_kv_heads"] * m["head_dim"]
-    shapes = {
-        "ln1": (d,), "ln2": (d,),
-        "attn.wq": (d, q), "attn.wk": (d, kv), "attn.wv": (d, kv), "attn.wo": (q, d),
-        "mlp.wi_gate": (d, f), "mlp.wi_up": (d, f), "mlp.wo": (f, d),
-    }
-    if m["qkv_bias"]:
-        shapes.update({"attn.bq": (q,), "attn.bk": (kv,), "attn.bv": (kv,)})
-    return shapes
-
-
-def top_shapes(m: dict, vocab_rows: int) -> dict:
-    """``vocab_rows``: the embedding rows the program holds (its vocab
-    padded); rows past ``vocab_size`` are drawn but never read."""
-    d = m["d_model"]
-    return {"embed": (vocab_rows, d), "lm_head": (d, vocab_rows), "final_norm": (d,)}
+from bench.reference import family
 
 
 def _draw(key, spec, shape, dtype):
+    """``spec``: (name, init, scale); "normal" draws scale * N(0, 1),
+    "around_one" 1 + scale * N(0, 1).  A scale of None is 1/sqrt(fan_in),
+    the fan-in of a matrix or a bank of them ``[..., in, out]`` being
+    ``shape[-2]``."""
     _, init, scale = spec
     if scale is None:
-        scale = shape[0] ** -0.5
+        scale = shape[-2] ** -0.5
     x = jax.random.normal(key, shape, jnp.float32) * scale
     if init == "around_one":
         x = 1.0 + x
@@ -124,13 +92,16 @@ def _freeze(m: dict):
 
 def _layer_body(mf, key_data, index, dtype):
     m = dict(mf)
+    fam = family(m)
     key = jax.random.fold_in(jax.random.fold_in(_key(key_data), 1), index)
-    return _draw_all(key, _LAYER, layer_shapes(m), dtype)
+    return _draw_all(key, fam.LAYER, fam.layer_shapes(m), dtype)
 
 
 def _top_body(mf, key_data, vocab_rows, dtype):
+    m = dict(mf)
+    fam = family(m)
     key = jax.random.fold_in(_key(key_data), 0)
-    return _draw_all(key, _TOP, top_shapes(dict(mf), vocab_rows), dtype)
+    return _draw_all(key, fam.TOP, fam.top_shapes(m, vocab_rows), dtype)
 
 
 _layer = jax.jit(_layer_body, static_argnums=(0, 3))

@@ -4,13 +4,25 @@ XLA's own count of the reference's matmuls."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 import tinycell  # noqa: F401
-from bench import flops
+from bench import flops, harness
 
 # 2 layers, d 8, 2 query heads and 1 KV head of 4, d_ff 16, vocab 10
 M = {"n_layers": 2, "d_model": 8, "n_heads": 2, "n_kv_heads": 1, "head_dim": 4, "d_ff": 16,
-     "vocab_size": 10, "qkv_bias": True, "dtype": "bfloat16"}
+     "vocab_size": 10, "qkv_bias": True, "dtype": "bfloat16", "reference": "dense"}
+
+# The dense counts at the cells' shapes, as the benchmark read them before
+# the family's counts moved into bench/reference/dense.py: prefill_flops(m,
+# 4, 1024), decode_flops, decode_min_bytes and decode_attention_call at
+# (batch, 896), flash_fwd_call(m, 4, 1024).
+PINNED = {
+    "qwen1.5-4b.decode": (8, 26_847_496_110_080, 59_910_389_760, 10_058_880_000,
+                          (73_400_320, 73_482_240), (21_495_808_000, 83_886_080)),
+    "chatglm3-6b.decode": (16, 47_745_926_692_864, 197_836_931_072, 12_365_664_256,
+                           (234_881_024, 14_942_208), (34_393_292_800, 71_303_168)),
+}
 
 
 def test_parameter_counts():
@@ -64,3 +76,14 @@ def test_matmul_flops_agree_with_xla():
     matmul = 2 * flops.layer_matmul_params(M)
     assert matmul <= cost["flops"] < matmul * 1.25  # plus a few elementwise ops
     np.testing.assert_equal(flops.layer_matmul_params(M), sum(a * b for a, b in shapes))
+
+
+@pytest.mark.parametrize("cell", sorted(PINNED))
+def test_dense_counts_at_the_cells_shapes_are_pinned(cell):
+    m = harness.load_cell(cell).model
+    B, prefill, decode, nbytes, attn, flash = PINNED[cell]
+    assert flops.prefill_flops(m, 4, 1024) == prefill
+    assert flops.decode_flops(m, B, 896) == decode
+    assert flops.decode_min_bytes(m, B, 896) == nbytes
+    assert flops.decode_attention_call(m, B, 896) == attn
+    assert flops.flash_fwd_call(m, 4, 1024) == flash

@@ -3,6 +3,8 @@ program's model at both configurations' smoke sizes, in float32 on the CPU:
 the prefill's last logits, then decoding through the cache, teacher-forced
 on fixed tokens.  Same weights (bench/weights.py) on both sides."""
 
+import hashlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,8 +12,10 @@ import pytest
 
 import tinycell  # noqa: F401  (puts the repository on sys.path)
 from bench import weights
+from bench.harness import model_block
 from bench.reference import dense
 from repro.configs import get_smoke_config
+from repro.launch.serve import pad_cache
 from repro.models.model import Model
 
 P, STEPS, N = 12, 5, 3
@@ -20,7 +24,7 @@ P, STEPS, N = 12, 5, 3
 @pytest.mark.parametrize("name", sorted(tinycell.TINY))
 def test_reference_matches_program_prefill_and_decode(name):
     spec = tinycell.TINY[name]
-    m = {**spec["model"], "dtype": "float32"}
+    m = {**model_block(spec), "dtype": "float32"}
     cfg = get_smoke_config(spec["program_config"]).replace(
         compute_dtype="float32", param_dtype="float32")
     model = Model(cfg)
@@ -32,7 +36,7 @@ def test_reference_matches_program_prefill_and_decode(name):
     want = np.asarray(dense.logits(m, key, rows, tokens, P - 1))  # [N, STEPS + 1, V]
     logits, cache = model.prefill(params, {"inputs": jnp.asarray(tokens[:, :P])})
     got = [logits[:, -1]]
-    cache = {k: jnp.pad(v, ((0, 0), (0, 0), (0, STEPS), (0, 0), (0, 0))) for k, v in cache.items()}
+    cache = pad_cache(cfg, dict(cache), P + STEPS)  # on the seq axis of the cache's order
     for i in range(STEPS):
         logits, cache = model.decode_step(params, cache, jnp.asarray(tokens[:, P + i : P + i + 1]),
                                           P + i)
@@ -44,7 +48,7 @@ def test_reference_matches_program_prefill_and_decode(name):
 
 
 def test_layer_by_layer_weights_equal_the_served_tree():
-    m = tinycell.TINY["tiny-chatglm"]["model"]
+    m = model_block(tinycell.TINY["tiny-chatglm"])
     key = weights.root_key(7)
     tree = weights.make_params(m, key, 128, m["dtype"])
     for i in range(m["n_layers"]):
@@ -61,10 +65,34 @@ def test_seeds_past_32_bits_differ():
 
 
 def test_fp8_control_departs_from_the_reference():
-    m = tinycell.TINY["tiny-qwen"]["model"]
+    m = model_block(tinycell.TINY["tiny-qwen"])
     key = weights.root_key(11)
     tokens = np.random.default_rng(1).integers(0, m["vocab_size"], (2, 16), np.int32)
     f32 = np.asarray(dense.logits(m, key, 128, tokens, 8))
     f8 = np.asarray(dense.logits(m, key, 128, tokens, 8, quant="fp8"))
     err = np.abs(f8 - f32).max() / np.abs(f32).max()
     assert 1e-3 < err < 0.5
+
+
+# sha256 over each leaf's path and bytes of make_params(m, root_key(2**33 +
+# 5), 256, "bfloat16"), as drawn before the leaf tables moved into
+# bench/reference/dense.py: the dense weights are the same bits.
+DIGESTS = {
+    "tiny-chatglm": "618f58b8f03b1b12139de974298c9c6efa1f2f7719f8171801530d07b747f639",
+    "tiny-qwen": "13669583c162413304a21bafaff48a8278535251ffe57156f868d9a6d7504012",
+}
+
+
+def _digest(tree) -> str:
+    h = hashlib.sha256()
+    for path, x in jax.tree_util.tree_flatten_with_path(tree)[0]:
+        h.update(jax.tree_util.keystr(path).encode())
+        h.update(np.asarray(x).tobytes())
+    return h.hexdigest()
+
+
+@pytest.mark.parametrize("name", sorted(DIGESTS))
+def test_dense_weights_are_the_recorded_draw(name):
+    m = model_block(tinycell.TINY[name])
+    assert _digest(weights.make_params(m, weights.root_key(2**33 + 5), 256, "bfloat16")) \
+        == DIGESTS[name]

@@ -1,7 +1,8 @@
 """The program's scopes and spans read from a trace (bench/scope_trace.py):
-device time per scope over a program's runs, the per-step times of layers,
-idle gaps named by the ``Server``'s spans, an ``.xplane.pb`` read with its
-ops' metadata, and the committed excerpts."""
+device time per scope over a program's runs, the per-step times of layers
+that the scoped metric readers declare, idle gaps named by the ``Server``'s
+spans, an ``.xplane.pb`` read with its ops' metadata, and the committed
+excerpts."""
 
 import pytest
 
@@ -54,15 +55,43 @@ def test_scope_ns_sums_ops_inside_whole_runs():
     assert sum(ns.values()) == pytest.approx(2 * 40.0)  # the buckets add up to the runs
 
 
+STEP = st.step_metrics()
+
+
+def step_ms(trace, name):
+    return st.scope_ms(trace, *STEP[name])
+
+
 def test_step_ms_reads_the_layers():
-    assert st.step_ms(TRACE, "decode_kv_ms") == pytest.approx((12 + 4) / 2 * 1e-6)
-    assert st.step_ms(TRACE, "decode_linear_ms") == pytest.approx((8 + 20) / 2 * 1e-6)
-    assert st.step_ms(TRACE, "prefill_attn_ms") == pytest.approx((40 + 10) * 1e-6)
+    assert STEP == {
+        "decode_kv_ms": ("jit_decode_step", ("kv_write", "kv_layout")),
+        "decode_weights_ms": ("jit_decode_step", ("layers",)),
+        "decode_linear_ms": ("jit_decode_step", ("embed", "qkv", "attn_out", "ffn", "logits")),
+        "prefill_attn_ms": ("jit_prefill", ("attn_core", "kv_layout")),
+    }
+    assert step_ms(TRACE, "decode_kv_ms") == pytest.approx(4 / 2 * 1e-6)
+    assert step_ms(TRACE, "decode_weights_ms") == pytest.approx(12 / 2 * 1e-6)
+    assert step_ms(TRACE, "decode_linear_ms") == pytest.approx((8 + 20) / 2 * 1e-6)
+    assert step_ms(TRACE, "prefill_attn_ms") == pytest.approx((40 + 10) * 1e-6)
     bare = st.ScopedTrace(TRACE.window, TRACE.ops, TRACE.modules, TRACE.host)
     assert bare.scope_ns("jit_decode_step") == (2, {"": 80.0})
-    assert all(st.step_ms(bare, name) is None for name in st.STEP_MS)  # a program without scopes
+    assert all(step_ms(bare, name) is None for name in STEP)  # a program without scopes
     none = st.ScopedTrace(TRACE.window, TRACE.ops, {DEV: []}, TRACE.host, TRACE.scopes)
-    assert st.step_ms(none, "decode_kv_ms") is None
+    assert step_ms(none, "decode_kv_ms") is None
+
+
+@pytest.mark.parametrize("name", sorted(STEP))
+def test_scoped_readers_read_through_scope_ms(name):
+    """Each reader returns its step time from the run's trace, and None,
+    without failing, on a program without scopes and on an untraced run."""
+    from bench import harness
+
+    cell = harness.load_cell("qwen1.5-4b.decode")
+    run = lambda trace: harness.Run(cell, harness.Window(0.0, 1.0, [], [1]), 1.0, 1.0, {}, trace)
+    assert harness.read_metric(name, run(TRACE)) == step_ms(TRACE, name)
+    bare = st.ScopedTrace(TRACE.window, TRACE.ops, TRACE.modules, TRACE.host)
+    assert harness.read_metric(name, run(bare)) is None
+    assert harness.read_metric(name, run(None)) is None
 
 
 def test_idle_gaps_named_by_the_program():
@@ -161,25 +190,28 @@ def test_unscoped_excerpt_reads_as_before():
         assert new.kernel_runs(kernel) == old.kernel_runs(kernel)
     assert new.top_ops() == old.top_ops() and new.idle_gaps() == old.idle_gaps()
     assert new.program_gaps() == old.idle_gaps()
-    assert all(st.step_ms(new, name) is None for name in st.STEP_MS)
+    assert all(step_ms(new, name) is None for name in STEP)
 
 
 def test_scoped_decode_excerpt_reads_as_the_chip_run():
     """Three decode steps of a traced qwen1.5-4b.decode run on a TPU v5e
     (seed 2718281801), reduced by ``scope_trace.load`` and cut to the
     steps and 0.2 ms either side.  The run printed, over its 71 steps,
-    decode_kv_ms 13.4954 and decode_linear_ms 8.1706; of each step 26.86%
-    of the device time is in no scope: two copies of the whole stacked
-    cache that XLA puts after the layer scan, with no metadata."""
+    13.4954 ms in the cache's scopes and the layer scan's own ops together
+    (``kv_write``, ``kv_layout``, ``layers``; that program sliced the cache
+    in the scan) and decode_linear_ms 8.1706; of each step 26.86% of the
+    device time is in no scope: two copies of the whole stacked cache that
+    XLA puts after the layer scan, with no metadata."""
     t = st.ScopedTrace.from_json((DATA / "qwen1.5-4b.decode.scoped.excerpt.json").read_text())
     runs, ns = t.scope_ns("jit_decode_step")
     assert runs == 3 and t.module_runs("jit_decode_step") == (3, pytest.approx(113.69108e6))
     assert sum(ns.values()) == pytest.approx(113.69108e6, rel=1e-3)  # buckets cover the runs
-    assert st.step_ms(t, "decode_kv_ms") == pytest.approx(13.678129, rel=1e-6)
-    assert st.step_ms(t, "decode_linear_ms") == pytest.approx(8.28154, rel=1e-6)
-    assert st.step_ms(t, "decode_kv_ms") == pytest.approx(13.4954, rel=0.02)
-    assert st.step_ms(t, "decode_linear_ms") == pytest.approx(8.1706, rel=0.02)
-    assert st.step_ms(t, "prefill_attn_ms") is None
+    kv, weights = step_ms(t, "decode_kv_ms"), step_ms(t, "decode_weights_ms")
+    assert (kv, weights) == (pytest.approx(0.203236, rel=1e-5), pytest.approx(13.474893, rel=1e-6))
+    assert step_ms(t, "decode_linear_ms") == pytest.approx(8.28154, rel=1e-6)
+    assert kv + weights == pytest.approx(13.4954, rel=0.02)
+    assert step_ms(t, "decode_linear_ms") == pytest.approx(8.1706, rel=0.02)
+    assert step_ms(t, "prefill_attn_ms") is None
     assert 100 * ns[""] / sum(ns.values()) == pytest.approx(26.86, abs=1.0)
     unscoped = {tr_family(op[0]) for run in t.runs("jit_decode_step") for op, s in run
                 if not s and op[2] > 1e6}
@@ -206,4 +238,4 @@ def test_the_operators_recipe_reads_a_live_servers_spans(tmp_path):
         jax.block_until_ready(logits)
     t = st.load(next(tmp_path.rglob("*.xplane.pb")))
     assert [name for name, _, _ in t.program] == ["serve.prefill", "serve.decode"]
-    assert st.report(t) == {"step_ms": dict.fromkeys(st.STEP_MS), "gaps": []}
+    assert st.report(t) == {"step_ms": dict.fromkeys(STEP), "gaps": []}

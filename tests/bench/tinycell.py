@@ -13,13 +13,14 @@ for p in (str(REPO), str(REPO / "src")):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-# the program's smoke configurations (repro.configs ... smoke())
+# the program's smoke configurations (repro.configs ... smoke()), each with
+# its family's reference module
 TINY = {
-    "tiny-qwen": {"program_config": "qwen1_5_4b", "model": {
+    "tiny-qwen": {"program_config": "qwen1_5_4b", "reference": "dense", "model": {
         "n_layers": 2, "d_model": 64, "n_heads": 4, "n_kv_heads": 4, "head_dim": 16,
         "d_ff": 160, "vocab_size": 128, "rope": "default", "rope_theta": 5000000.0,
         "norm_eps": 1e-06, "qkv_bias": True, "tie_embeddings": False, "dtype": "bfloat16"}},
-    "tiny-chatglm": {"program_config": "chatglm3_6b", "model": {
+    "tiny-chatglm": {"program_config": "chatglm3_6b", "reference": "dense", "model": {
         "n_layers": 2, "d_model": 64, "n_heads": 4, "n_kv_heads": 2, "head_dim": 16,
         "d_ff": 160, "vocab_size": 128, "rope": "half", "rope_theta": 10000.0,
         "norm_eps": 1e-06, "qkv_bias": True, "tie_embeddings": False, "dtype": "bfloat16"}},
@@ -30,18 +31,22 @@ MIXES = {
 }
 
 
-def make_root(tmp: Path, limit: float | None = 1.0, sample: int = 4) -> Path:
+def make_root(tmp: Path, limit: float | None = 1.0, sample: int = 4,
+              configs: dict = TINY) -> Path:
     """Write BENCHMARK.json and the cells' files under ``tmp``: one cell per
-    (tiny configuration, mix), named ``<config>.<mix>``, sharing the
-    repository's metric readers."""
+    (tiny configuration, mix), named ``<config>.<mix>``, with a copy of the
+    repository's metric readers.  ``configs``: {name: {"program_config",
+    "reference", "model"}}, smoke presets of the program."""
     bench = tmp / "bench"
     for sub in ("configs", "traffic", "cells"):
         (bench / sub).mkdir(parents=True, exist_ok=True)
+    shutil.copytree(REPO / "bench" / "metrics", bench / "metrics", dirs_exist_ok=True,
+                    ignore=shutil.ignore_patterns("__pycache__"))
     manifest = json.loads((REPO / "BENCHMARK.json").read_text())
     manifest["configs"], manifest["workloads"] = [], []
-    for name, spec in TINY.items():
+    for name, spec in configs.items():
         (bench / "configs" / f"{name}.json").write_text(json.dumps(
-            {**spec, "program_preset": "smoke", "reference": "dense", "model_from": {}}))
+            {**spec, "program_preset": "smoke", "model_from": {}}))
         manifest["configs"].append({"name": name, "source": "smoke()", "reduced": [],
                                     "file": f"bench/configs/{name}.json", "why": "tests"})
         for mix, sizes in MIXES.items():
